@@ -5,6 +5,8 @@ metrics — the paper's §5.1 setup at laptop scale.
 Run: PYTHONPATH=src python examples/train_moe_lm.py [--steps 300]
 """
 import argparse
+import os
+import tempfile
 
 import jax
 
@@ -20,7 +22,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--experts", type=int, default=64)
-    ap.add_argument("--workdir", default="/tmp/repro_moe_lm")
+    ap.add_argument("--workdir", default=os.path.join(
+        tempfile.gettempdir(), "repro_moe_lm"))
     args = ap.parse_args()
 
     # MoE-64 with ~1M-param experts (the paper's expert size), d_model 256.

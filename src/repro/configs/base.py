@@ -113,7 +113,8 @@ class ModelConfig:
     # Serve-time fused decode step (docs/kernels.md §Fused decode step):
     # decode-shaped MoE/MoA calls run routing + dispatch + expert FFN +
     # combine as ONE kernel launch per layer.  Inference-only — train and
-    # prefill paths ignore it; greedy outputs are bit-identical on/off.
+    # prefill paths ignore it; outputs match the unfused path within
+    # float tolerance.
     fused_decode: bool = False
 
     def replace(self, **kw) -> "ModelConfig":

@@ -21,6 +21,17 @@ TPU mapping (shard_map, explicit collectives):
 
 This is the schedule the GSPMD path must be compared against in §Perf: a2a
 moves ``2 * k * tokens * d_model`` bytes per layer, independent of E.
+
+:func:`moe_apply_expert_sharded` is the serving counterpart: every device
+sees the whole token batch (so routing is the one global decision a
+single device would make), runs its own experts' share of the capacity
+buffer — experts split over the expert axis, expert width over the
+within-expert axis, exactly as the weights are stored — and one psum sums
+the experts' contributions.  No all-to-all, no weight movement.
+
+Both schedules exist because a compiled Pallas TPU kernel is opaque to
+the SPMD partitioner: on a multi-device mesh it must run inside a
+shard_map, on per-shard blocks.
 """
 from __future__ import annotations
 
@@ -32,9 +43,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import losses
 from repro.core import router as router_lib
-from repro.core.moe import MoEArgs
+from repro.core.moe import MoEArgs, moe_defs
 from repro.kernels import backend as backend_lib
 from repro.sharding import context as ctx_lib
+from repro.sharding import partition
 
 
 def _local_moe(params, x_local, mask_local, a: MoEArgs, *, train, rng,
@@ -44,8 +56,8 @@ def _local_moe(params, x_local, mask_local, a: MoEArgs, *, train, rng,
                body_ctx: ctx_lib.MeshContext | None):
     """Body executed per shard under shard_map.
 
-    ``ep`` is the ep-axis size, passed from the mesh at the shard_map
-    boundary (0.4.x jax cannot query a mapped axis's size by name).
+    ``ep`` is the ep-axis size, read from the mesh at the shard_map
+    boundary (a static Python int, so the buffer reshapes stay static).
     ``bk`` is the resolved kernel backend; ``router`` the resolved Router
     (routing runs locally on each shard's tokens — data-parallel gating,
     §3.2); ``body_ctx`` the Manual-mode context the backend ops use to
@@ -165,7 +177,7 @@ def moe_apply_ep(params, x, a: MoEArgs, mesh: Mesh | None = None, *,
             "moe_apply_ep needs a mesh (ctx or positional)")
     bk = backend_lib.resolve(a)     # explicit: raises on unknown/broken
     router = router_lib.build(a, topk_impl=bk.topk_impl)
-    # Context for the shard_map body: every mesh axis is Manual on 0.4.x,
+    # Context for the shard_map body: it is manual over every mesh axis,
     # so backend ops derive per-shard [E/ep, C, d] block specs from it.
     # Only meaningful when the plan's expert axis is the ep axis we use.
     body_ctx = (ctx or ctx_lib.MeshContext.for_mesh(mesh)).manual(
@@ -200,3 +212,88 @@ def moe_apply_ep(params, x, a: MoEArgs, mesh: Mesh | None = None, *,
     return ctx_lib.shard_map(fn, mesh,
                              (w_specs, token_spec, mask_spec),
                              (token_spec, aux_spec))(params, x, mask)
+
+
+def _axes_of(spec_entry) -> tuple[str, ...]:
+    if spec_entry is None:
+        return ()
+    return spec_entry if isinstance(spec_entry, tuple) else (spec_entry,)
+
+
+def moe_apply_expert_sharded(params, x, a: MoEArgs, *,
+                             ctx: ctx_lib.MeshContext,
+                             mask: jax.Array | None = None):
+    """Inference MoE over a flat token batch x: [T, d_model] on ``ctx``'s
+    mesh, with the expert weights where the plan stores them.
+
+    Every device routes the whole batch (the same decision one device
+    would make: capacity, drops and telemetry are global), keeps the
+    assignments to its own experts (expert axis rank) and runs them
+    through the backend's dispatch -> expert FFN -> combine on its shard
+    of the expert width; the f32 partial outputs are summed with one psum
+    over the expert and width axes.  Returns ``(y, aux)`` like
+    :func:`repro.core.moe.moe_apply` with ``train=False``.
+    """
+    mesh = ctx.mesh
+    if mesh is None or ctx.manual_axes:
+        raise RuntimeError(
+            "moe_apply_expert_sharded needs a concrete mesh and opens its "
+            "own shard_map (no enclosing Manual axes)")
+    bk = backend_lib.resolve(a)
+    if a.fused_decode:
+        backend_lib.record_fallback(
+            "decode_step", "experts are sharded over the mesh",
+            "the unfused kernel pipeline")
+    router = router_lib.build(a, topk_impl=bk.topk_impl)
+    defs = moe_defs(a)
+    w_specs = {n: partition.resolve_spec(ctx.rules, mesh, defs[n].shape,
+                                         defs[n].axes)
+               for n in ("w1", "w2", "w3") if n in params}
+    ep_axes = _axes_of(w_specs["w1"][0])
+    width_axes = _axes_of(w_specs["w1"][2])
+    if _axes_of(w_specs["w2"][1]) != width_axes or (
+            "w3" in w_specs and w_specs["w3"] != w_specs["w1"]):
+        raise RuntimeError(f"inconsistent expert weight specs {w_specs}")
+    ep = 1
+    for ax in ep_axes:
+        ep *= mesh.shape[ax]
+    e_local = a.n_experts // ep
+    body_ctx = ctx.manual(*mesh.axis_names)
+    sum_axes = ep_axes + width_axes
+    token_axis = "tokens" if a.wide_dispatch else "batch"
+    x = ctx_lib.with_constraint(x, (token_axis, "embed"), ctx)
+
+    def body(p, x_all, m):
+        dec = router.route(p, x_all, train=False, rng=None, mask=m)
+        plan = dec.plan
+        lo = jax.lax.axis_index(ep_axes) * e_local if ep_axes else 0
+        mine = ((plan.expert_index >= lo)
+                & (plan.expert_index < lo + e_local))
+        local = plan._replace(
+            expert_index=jnp.where(mine, plan.expert_index - lo, 0),
+            position=jnp.where(mine, plan.position, plan.capacity),
+            n_experts=e_local)
+        buf = bk.dispatch(x_all, local, a, ctx=body_ctx)
+        out = bk.expert_ffn(p, buf, a, ctx=body_ctx)
+        y = bk.combine(out, local, a, dtype=jnp.float32, ctx=body_ctx)
+        if sum_axes:
+            y = jax.lax.psum(y, sum_axes)
+        return y.astype(x_all.dtype), {"aux_loss": dec.aux_loss,
+                                       "metrics": dec.metrics,
+                                       "telemetry": dec.telemetry}
+
+    p_specs = {"gate": jax.tree_util.tree_map(lambda _: P(),
+                                              params["gate"]),
+               **w_specs}
+    if "thresholds" in params:      # Appendix-F policy params: replicated
+        p_specs["thresholds"] = jax.tree_util.tree_map(
+            lambda _: P(), params["thresholds"])
+    params = {n: params[n] for n in p_specs}
+    if mask is None:
+        mask = jnp.ones((x.shape[0],), jnp.float32)
+    # Every output is the same on every device: the routing is global and
+    # y is psum'd, so P() (replicated) covers the whole tree.
+    y, aux = ctx_lib.shard_map(body, mesh, (p_specs, P(), P()),
+                               (P(), P()))(params, x, mask)
+    y = ctx_lib.with_constraint(y, (token_axis, "embed"), ctx)
+    return y, aux

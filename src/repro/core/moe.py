@@ -166,7 +166,7 @@ def moe_apply(params, x: jax.Array, a: MoEArgs, *, train: bool = True,
     bk = backend_lib.resolve(a)     # explicit: raises on unknown/broken
     if not train and a.fused_decode and bk.decode_step is not None:
         # One-launch decode step: the backend fuses routing -> scatter ->
-        # expert FFN -> combine (bit-identical to the pipeline below) and
+        # expert FFN -> combine (the pipeline below, to float tolerance) and
         # emits the same load/overflow telemetry families.  Decode
         # consumers discard losses/metrics, so aux carries zeros.
         token_axis = "tokens" if a.wide_dispatch else "batch"

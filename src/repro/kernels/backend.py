@@ -43,8 +43,10 @@ Backends consume the explicit sharding context (ROADMAP open item 3):
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import logging
+import warnings
 from typing import Callable
 
 import jax
@@ -59,6 +61,26 @@ log = logging.getLogger(__name__)
 
 class KernelBackendError(RuntimeError):
     """Unknown, broken, or mis-shaped kernel backend — never swallowed."""
+
+
+# Calls that left the pallas kernels for a slower path, by call site
+# ("dispatch", "combine", "decode_step", "decode_proj").  Counted where the
+# decision is made — at trace time, once per traced shape — so a run can
+# assert that its kernels really ran (chip_smoke.py fails on any).
+_FALLBACKS: collections.Counter = collections.Counter()
+
+
+def fallbacks() -> dict[str, int]:
+    """Kernel fallbacks taken in this process so far, by call site."""
+    return dict(_FALLBACKS)
+
+
+def record_fallback(what: str, why: str, to: str) -> None:
+    """Count a fallback and say so, in the log and as a RuntimeWarning."""
+    _FALLBACKS[what] += 1
+    msg = f"pallas {what}: {why}; falling back to {to} for this call"
+    log.warning(msg)
+    warnings.warn(msg, RuntimeWarning, stacklevel=3)
 
 
 # ---------------------------------------------------------------------------
@@ -151,6 +173,10 @@ class KernelBackend:
     # (x, w [E,K,N], plan_in, plan_out, a, *, dtype=None, ctx=None) ->
     # [T_out, N] — fuses dispatch(plan_in) -> gmm -> combine(plan_out).
     decode_proj: Callable | None = None
+    # True when the ops are compiled kernels the SPMD partitioner cannot
+    # split (Mosaic): on a multi-device mesh they must run inside a
+    # shard_map on per-shard blocks (transformer._moe_schedule).
+    needs_shard_map: bool = False
 
 
 _REGISTRY: dict[str, "KernelBackend | Exception"] = {}
@@ -230,7 +256,7 @@ def _decode_step_via(bk: "KernelBackend", params, x, a, *, mask=None,
                      ctx=None):
     """Route -> dispatch -> expert FFN -> combine through ``bk``'s ops, in
     exactly ``moe_apply``'s order and constraint placement — the unfused
-    semantics the fused kernel must be bit-identical to."""
+    semantics the fused kernel must match."""
     from repro.core import router as router_lib
     router = router_lib.build(a, topk_impl=bk.topk_impl)
     dec = router.route(params, x, train=False, rng=None, mask=mask)
@@ -352,11 +378,11 @@ def _register_pallas() -> None:
         ``e_block=None`` keeps the whole [E, C, d] buffer VMEM-resident;
         an int runs the E-blocked kernels with that slab size.  The
         selection comes from ``dispatch_lib.select_e_block`` against the
-        (configurable) budget, so past ~16 MiB the backend now *blocks*
-        the expert dimension instead of bailing — only a shape whose
+        (configurable) budget, so past ~16 MiB the backend *blocks* the
+        expert dimension instead of bailing — only a shape whose
         single-expert slab still exceeds the budget falls back to the ref
-        scatter (with a warning).  ``MoEArgs.dispatch_e_block`` forces a
-        slab size explicitly."""
+        scatter (counted, with a warning).  ``MoEArgs.dispatch_e_block``
+        forces a slab size explicitly."""
         forced = getattr(a, "dispatch_e_block", None)
         if forced is not None:
             return True, forced
@@ -364,11 +390,9 @@ def _register_pallas() -> None:
         try:
             return True, dispatch_lib.select_e_block(
                 n_experts, capacity, d, dtype, n_tokens=n_tokens,
-                limit=limit)
+                limit=limit, op=what)
         except dispatch_lib.DispatchVMEMError as err:
-            log.warning(
-                "pallas %s: %s; falling back to the ref path for this "
-                "call", what, err)
+            record_fallback(what, str(err), "the ref scatter")
             return False, None
 
     def _pallas_expert_ffn(params, x, a, *, ctx=None):
@@ -433,18 +457,17 @@ def _register_pallas() -> None:
     def _fused_budget_ok(a, need: int, what: str) -> bool:
         """Guard the fused decode slab against the VMEM budget.  Everything
         (weights included) is resident for the single grid step, so past
-        the limit we warn *loudly* (RuntimeWarning — same contract as the
-        dispatch VMEM fallback) and run the unfused pallas pipeline."""
+        the limit the call is counted as a fallback, warned about
+        (RuntimeWarning — same contract as the dispatch VMEM fallback) and
+        runs the unfused pallas pipeline."""
         limit = (getattr(a, "dispatch_vmem_limit", None)
                  or dispatch_lib.DEFAULT_VMEM_LIMIT)
         if need <= limit:
             return True
-        import warnings
-        warnings.warn(
-            f"pallas {what}: fused slab needs ~{need / 1e6:.1f} MB VMEM "
-            f"> limit {limit / 1e6:.1f} MB; falling back to the unfused "
-            "kernel pipeline for this call (docs/kernels.md §Fused decode "
-            "step)", RuntimeWarning, stacklevel=3)
+        record_fallback(
+            what, f"fused slab needs ~{need / 1e6:.1f} MB VMEM > limit "
+            f"{limit / 1e6:.1f} MB", "the unfused kernel pipeline "
+            "(docs/kernels.md §Fused decode step)")
         return False
 
     def _pallas_decode_step(params, x, a, *, mask=None, ctx=None):
@@ -473,7 +496,8 @@ def _register_pallas() -> None:
                 y, load, overflow = ops.fused_decode_step(
                     x, valid, params["gate"]["wg"], params["w1"],
                     params["w2"], params.get("w3") if gated else None,
-                    k=k, capacity=capacity, activation=a.activation)
+                    k=k, capacity=capacity, activation=a.activation,
+                    vmem_limit=getattr(a, "dispatch_vmem_limit", None))
                 return y, {"expert_load": load, "overflow": overflow}
             # Any other policy (expert_choice's batch-global column top-k,
             # Appendix-F batchwise/threshold, priority dispatch): routing
@@ -491,7 +515,8 @@ def _register_pallas() -> None:
             y = ops.fused_routed_apply(
                 x, p, p, params["w1"], params["w2"],
                 params.get("w3") if gated else None,
-                mode="ffn", activation=a.activation, out_dtype=x.dtype)
+                mode="ffn", activation=a.activation, out_dtype=x.dtype,
+                vmem_limit=getattr(a, "dispatch_vmem_limit", None))
             return y, dec.telemetry
 
     def _pallas_decode_proj(x, w, plan_in, plan_out, a, *, dtype=None,
@@ -510,7 +535,8 @@ def _register_pallas() -> None:
                                         a, dtype=dtype, ctx=ctx)
             return ops.fused_routed_apply(
                 x, p_in, p_out, w, mode="proj",
-                out_dtype=dtype or x.dtype)
+                out_dtype=dtype or x.dtype,
+                vmem_limit=getattr(a, "dispatch_vmem_limit", None))
 
     def _pallas_gmm(x, w, a, *, ctx=None):
         tiles = {}
@@ -528,7 +554,8 @@ def _register_pallas() -> None:
                            combine=_pallas_combine,
                            topk_impl=_pallas_topk, gmm=_pallas_gmm,
                            decode_step=_pallas_decode_step,
-                           decode_proj=_pallas_decode_proj))
+                           decode_proj=_pallas_decode_proj,
+                           needs_shard_map=True))
 
 
 _register_pallas()

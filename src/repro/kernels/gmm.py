@@ -41,8 +41,8 @@ dominates, so fewer/bigger blocks win by integer factors (the
 ``kernel_backend_gmm_pallas`` gap in BENCH_micro.json).  Explicit
 ``bm/bn/bk`` arguments always override the table.
 
-On this CPU build host kernels run in interpret mode (the kernel body
-executes as Python/jnp); ``interpret=False`` is the TPU path.
+``interpret`` resolves at call time through ``platform.interpret_mode``:
+compiled on a TPU, the Pallas interpreter elsewhere.
 """
 from __future__ import annotations
 
@@ -55,6 +55,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import platform
 
 
 def round_up(x: int, m: int) -> int:
@@ -264,6 +266,7 @@ def _gmm_raw(x: jax.Array, w: jax.Array, activation: str,
                                lambda e, m, n_, k_: (e, m, n_)),
         out_shape=jax.ShapeDtypeStruct((e, bp.c, bp.n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bp.bm, bp.bn), jnp.float32)],
+        compiler_params=platform.compiler_params(),
         interpret=interpret,
     )(xp, wp)
     if (bp.c, bp.n) != (c, n):
@@ -300,7 +303,7 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
                                              "interpret"))
 def gmm(x: jax.Array, w: jax.Array, *, activation: str = "none",
         bm: int | None = None, bn: int | None = None, bk: int | None = None,
-        interpret: bool = True) -> jax.Array:
+        interpret: bool | None = None) -> jax.Array:
     """[E, C, K] x [E, K, N] -> [E, C, N] with optional fused activation.
 
     Differentiable (custom VJP); non-tile-aligned C/K/N are zero-padded to
@@ -309,4 +312,5 @@ def gmm(x: jax.Array, w: jax.Array, *, activation: str = "none",
     :func:`plan_blocks` — each backward-pass GMM re-plans for its own
     operand shapes, so grad matmuls get their own tuned tiles.
     """
-    return _gmm(x, w, activation, bm, bn, bk, interpret)
+    return _gmm(x, w, activation, bm, bn, bk,
+                platform.interpret_mode(interpret))

@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On TPU these dispatch the compiled kernels; on the CPU build host they run
+On a TPU these run the compiled kernels; on any other platform they run
 in interpret mode (kernel bodies executed with jnp), which is how the
-allclose tests against ``ref.py`` validate them.
+allclose tests against ``ref.py`` validate them.  The choice is made per
+call by ``platform.interpret_mode`` — importing this module touches no
+JAX backend.
 
 All ops are differentiable (each kernel carries a ``jax.custom_vjp``).
 The MeshContext-aware layer lives one level up in
@@ -13,7 +15,6 @@ buffers against it before handing the local shapes to these wrappers
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import dispatch as dispatch_lib
@@ -21,12 +22,9 @@ from repro.kernels import fused_decode as fused_lib
 from repro.kernels import gmm as gmm_lib
 from repro.kernels import topk_gating as topk_lib
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 def gmm(x, w, *, activation: str = "none", bm=None, bn=None, bk=None):
-    return gmm_lib.gmm(x, w, activation=activation, bm=bm, bn=bn, bk=bk,
-                       interpret=_INTERPRET)
+    return gmm_lib.gmm(x, w, activation=activation, bm=bm, bn=bn, bk=bk)
 
 
 def expert_ffn(params, x, *, activation: str = "relu",
@@ -52,8 +50,7 @@ def expert_ffn(params, x, *, activation: str = "relu",
 
 
 def topk_gating(logits, k: int, block_t: int = 256):
-    return topk_lib.topk_gating(logits, k, block_t=block_t,
-                                interpret=_INTERPRET)
+    return topk_lib.topk_gating(logits, k, block_t=block_t)
 
 
 def topk_gating_full(logits, k: int, extra: int = 0, block_t: int = 256):
@@ -62,8 +59,7 @@ def topk_gating_full(logits, k: int, extra: int = 0, block_t: int = 256):
     The ``extra`` raw values feed the Appendix-A load estimator (the noisy
     gating path needs the (k+1)-th noisy logit as threshold).
     """
-    return topk_lib.topk_gating_full(logits, k, extra, block_t=block_t,
-                                     interpret=_INTERPRET)
+    return topk_lib.topk_gating_full(logits, k, extra, block_t=block_t)
 
 
 def dispatch(x, eidx, pos, *, n_experts: int, capacity: int,
@@ -75,23 +71,24 @@ def dispatch(x, eidx, pos, *, n_experts: int, capacity: int,
     ``DispatchVMEMError`` only when even a one-expert slab exceeds it
     (see kernels/dispatch.py)."""
     return dispatch_lib.dispatch(x, eidx, pos, n_experts=n_experts,
-                                 capacity=capacity, interpret=_INTERPRET,
-                                 vmem_limit=vmem_limit, e_block=e_block)
+                                 capacity=capacity, vmem_limit=vmem_limit,
+                                 e_block=e_block)
 
 
 def fused_decode_step(x, valid, wg, w1, w2, w3=None, *, k: int,
-                      capacity: int, activation: str = "relu"):
+                      capacity: int, activation: str = "relu",
+                      vmem_limit: int | None = None):
     """One fused MoE decode step (routing + scatter + expert FFN +
     combine in a single pallas launch).  Inference-only — no custom VJP;
     see kernels/fused_decode.py.  Returns (y, expert_load, overflow)."""
     return fused_lib.decode_step(x, valid, wg, w1, w2, w3, k=k,
                                  capacity=capacity, activation=activation,
-                                 interpret=_INTERPRET)
+                                 vmem_limit=vmem_limit)
 
 
 def fused_routed_apply(x, plan_in, plan_out, w1, w2=None, w3=None, *,
                        mode: str = "ffn", activation: str = "relu",
-                       out_dtype=None):
+                       out_dtype=None, vmem_limit: int | None = None):
     """Fused dispatch -> grouped matmul(s) -> combine over explicit
     ``DispatchPlan``s (any routing policy; MoA's assignment-major plan
     views included).  Inference-only; see kernels/fused_decode.py."""
@@ -100,7 +97,7 @@ def fused_routed_apply(x, plan_in, plan_out, w1, w2=None, w3=None, *,
         plan_out.expert_index, plan_out.position, plan_out.weight,
         w1, w2, w3, n_experts=plan_in.n_experts,
         capacity=plan_in.capacity, mode=mode, activation=activation,
-        out_dtype=out_dtype, interpret=_INTERPRET)
+        out_dtype=out_dtype, vmem_limit=vmem_limit)
 
 
 def combine(buf, w, eidx, pos, *, out_dtype=None,
@@ -109,5 +106,4 @@ def combine(buf, w, eidx, pos, *, out_dtype=None,
     :func:`dispatch`; raises ``DispatchVMEMError`` only when even a
     one-expert slab exceeds the budget (see kernels/dispatch.py)."""
     return dispatch_lib.combine(buf, w, eidx, pos, out_dtype=out_dtype,
-                                interpret=_INTERPRET,
                                 vmem_limit=vmem_limit, e_block=e_block)

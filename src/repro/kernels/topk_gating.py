@@ -32,24 +32,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.gmm import round_up as _round_up
+from repro.kernels import platform
 
 NEG = -1e30
 
 
+def _lanes(x: jax.Array) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+
+
+def max_lowest_index(work: jax.Array):
+    """Row max of [T, E] and the lowest lane holding it, both [T, 1] —
+    ``lax.top_k``'s tie order, written out: on the TPU ``jnp.argmax``
+    does not promise the first of equal maxima, and ties are the rule
+    when the gate starts at zero (Appendix A)."""
+    m = jnp.max(work, axis=-1, keepdims=True)
+    lane = _lanes(work).astype(jnp.float32)           # E < 2^24: exact
+    first = jnp.min(jnp.where(work == m, lane, float(work.shape[-1])),
+                    axis=-1, keepdims=True)
+    return m, first.astype(jnp.int32)
+
+
 def _topk_kernel(logits_ref, w_ref, idx_ref, vals_ref, *, k: int, kk: int):
     x = logits_ref[...].astype(jnp.float32)           # [T_blk, E]
-    t, e = x.shape
     vals = []
     idxs = []
     work = x
     for _ in range(kk):
-        m = jnp.max(work, axis=-1)                    # [T_blk]
-        i = jnp.argmax(work, axis=-1).astype(jnp.int32)
-        vals.append(m)
-        idxs.append(i)
-        work = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, (t, e), 1) == i[:, None],
-            NEG, work)
+        m, i = max_lowest_index(work)                # [T_blk, 1] each
+        vals.append(m[:, 0])
+        idxs.append(i[:, 0])
+        work = jnp.where(_lanes(work) == i, NEG, work)
     v = jnp.stack(vals, axis=-1)                      # [T_blk, kk]
     # softmax over the k kept entries (Eq. 3: Softmax(KeepTopK(...)))
     vk = v[:, :k]
@@ -84,6 +97,7 @@ def _topk_raw(logits, k, extra, block_t, interpret):
         out_shape=(jax.ShapeDtypeStruct((tp, k), jnp.float32),
                    jax.ShapeDtypeStruct((tp, kk), jnp.int32),
                    jax.ShapeDtypeStruct((tp, kk), jnp.float32)),
+        compiler_params=platform.compiler_params(),
         interpret=interpret,
     )(lp)
     if tp != t:
@@ -138,15 +152,17 @@ def _topk_int(logits, k, extra, block_t, interpret):
 @functools.partial(jax.jit, static_argnames=("k", "extra", "block_t",
                                              "interpret"))
 def topk_gating_full(logits: jax.Array, k: int, extra: int = 0, *,
-                     block_t: int = 256, interpret: bool = True):
+                     block_t: int = 256, interpret: bool | None = None):
     """logits: [T, E] -> (weights [T, k] f32 softmaxed over the top-k,
     indices [T, k+extra] i32, raw top values [T, k+extra] f32)."""
-    return _topk_int(logits, k, extra, block_t, interpret)
+    return _topk_int(logits, k, extra, block_t,
+                     platform.interpret_mode(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_t", "interpret"))
 def topk_gating(logits: jax.Array, k: int, *, block_t: int = 256,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """logits: [T, E] -> (weights [T, k] f32, indices [T, k] i32)."""
-    w, idx, _ = _topk_int(logits, k, 0, block_t, interpret)
+    w, idx, _ = _topk_int(logits, k, 0, block_t,
+                          platform.interpret_mode(interpret))
     return w, idx

@@ -17,6 +17,7 @@ import numpy as np
 import jax
 
 from repro.common import param as pm
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import get_config
 from repro.core import router as router_lib
 from repro.launch.mesh import make_host_mesh
@@ -44,6 +45,10 @@ def main():
     ap.add_argument("--policy", choices=("continuous", "static"),
                     default="continuous",
                     help="static = batch-drain baseline")
+    ap.add_argument("--kernel-backend", default=None,
+                    choices=["ref", "pallas"],
+                    help="MoE kernel backend override (docs/kernels.md); "
+                         "default: the arch config's choice")
     ap.add_argument("--router-policy", default=None,
                     help="routing policy override (docs/routing.md)")
     ap.add_argument("--capacity-factor", type=float, default=None,
@@ -84,8 +89,9 @@ def main():
     ap.add_argument("--fused-decode", action="store_true",
                     help="one fused kernel launch per MoE/MoA layer at "
                          "decode (routing + dispatch + expert FFN + "
-                         "combine; bit-identical greedy outputs — "
-                         "docs/kernels.md §Fused decode step)")
+                         "combine; outputs match the unfused path within "
+                         "float tolerance — docs/kernels.md §Fused "
+                         "decode step)")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a chrome-trace JSON of the run here "
                          "(Perfetto-loadable; docs/observability.md)")
@@ -98,10 +104,13 @@ def main():
                     help="record per-step scheduler StepDecision entries "
                          "(the replay simulator's fidelity contract)")
     args = ap.parse_args()
+    print(f"[serve] compile cache: {enable_compile_cache()}")
 
     cfg = get_config(args.arch)
     if args.reduce:
         cfg = reduced(cfg)
+    if args.kernel_backend is not None:
+        cfg = cfg.replace(kernel_backend=args.kernel_backend)
     if args.router_policy is not None or args.capacity_factor is not None:
         spec = router_lib.resolve_spec(cfg)
         if args.router_policy is not None:
@@ -166,7 +175,7 @@ def main():
     dt = time.perf_counter() - t0
     total = engine.stats["generated_tokens"]
     print(f"[serve] {args.requests} requests x {args.new_tokens} tokens in "
-          f"{dt:.2f}s ({total/dt:.1f} tok/s on this host, "
+          f"{dt:.2f}s ({total/dt:.1f} tok/s on {jax.default_backend()}, "
           f"policy={args.policy}, slots={n_slots}, "
           f"steps={engine.stats['decode_steps']}, "
           f"util={engine.slot_utilization:.2f})")

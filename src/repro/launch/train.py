@@ -13,11 +13,14 @@ Example:
 from __future__ import annotations
 
 import argparse
+import os
+import tempfile
 
 import jax
 import jax.numpy as jnp
 
 from repro.common import param as pm
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import get_config
 from repro.core import router as router_lib
 from repro.data.pipeline import DataConfig, DataIterator
@@ -87,11 +90,13 @@ def main():
     ap.add_argument("--moa-k", type=int, default=None,
                     help="MoA head-groups-per-token override (archs with "
                          "moa_positions; docs/moa.md)")
-    ap.add_argument("--workdir", default="/tmp/repro_train")
+    ap.add_argument("--workdir", default=os.path.join(
+        tempfile.gettempdir(), "repro_train"))
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a chrome-trace JSON of the run here "
                          "(train.step spans; docs/observability.md)")
     args = ap.parse_args()
+    print(f"[train] compile cache: {enable_compile_cache()}")
 
     cfg = get_config(args.arch)
     if args.reduce:

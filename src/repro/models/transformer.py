@@ -17,9 +17,11 @@ import jax.numpy as jnp
 from repro.common import param as pm
 from repro.common.param import ParamDef
 from repro.configs.base import LayerKind, ModelConfig, layer_kinds, n_periods
+from repro.core import expert_parallel as ep_lib
 from repro.core import hierarchical as hmoe
 from repro.core import moa as moa_lib
 from repro.core import moe as moe_lib
+from repro.kernels import backend as backend_lib
 from repro.models import attention, layers, ssm
 from repro.sharding import context as ctx_lib
 
@@ -225,6 +227,38 @@ def _add_telemetry(acc, aux):
     return out
 
 
+def _moe_schedule(a: moe_lib.MoEArgs, ctx, *, train: bool, n_tokens: int):
+    """The MoE layer function for this call: plain ``moe_apply`` (GSPMD
+    constraints), or one of ``core/expert_parallel.py``'s shard_map
+    schedules when a backend whose kernels the SPMD partitioner cannot
+    split (``KernelBackend.needs_shard_map``: compiled Mosaic kernels)
+    meets a multi-device mesh, so the kernels run on per-shard blocks.
+
+    Training on a (data, model) mesh whose experts split over model runs
+    the paper's §3.1 all-to-all schedule (``moe_apply_ep``): each shard
+    routes its own tokens with shard-local capacity, the data-parallel
+    gating of the paper.  Serving (prefill and decode) runs
+    ``moe_apply_expert_sharded``: one global routing decision, each device
+    computing its own experts' share.  Training on other meshes keeps
+    ``moe_apply``."""
+    if (ctx is None or ctx.mesh is None or ctx.manual_axes
+            or ctx.mesh.size == 1
+            or not backend_lib.resolve(a).needs_shard_map):
+        return moe_lib.moe_apply
+    mesh = ctx.mesh
+    if (train and set(mesh.axis_names) == {"data", "model"}
+            and ctx.rules.lookup("experts") == ("model",)
+            and a.n_experts % mesh.shape["model"] == 0
+            and n_tokens % mesh.size == 0):
+        return ep_lib.moe_apply_ep
+    if train:
+        # No shard_map training schedule for this mesh: GSPMD lowering of
+        # a compiled kernel then refuses loudly ("wrap in a shard_map").
+        return moe_lib.moe_apply
+    return lambda p, x, a_, *, train, rng, ctx, mask: \
+        ep_lib.moe_apply_expert_sharded(p, x, a_, ctx=ctx, mask=mask)
+
+
 def _apply_ffn(params, x, kind: LayerKind, cfg: ModelConfig, *, train, rng,
                ctx: ctx_lib.MeshContext | None = None, valid=None,
                decode: bool = False):
@@ -247,10 +281,10 @@ def _apply_ffn(params, x, kind: LayerKind, cfg: ModelConfig, *, train, rng,
                                      train=train, rng=rng, ctx=ctx,
                                      mask=mask)
         else:
-            y, aux = moe_lib.moe_apply(params["moe"], flat,
-                                       _moe_args(cfg, decode=decode),
-                                       train=train, rng=rng, ctx=ctx,
-                                       mask=mask)
+            args = _moe_args(cfg, decode=decode)
+            apply = _moe_schedule(args, ctx, train=train, n_tokens=b * s)
+            y, aux = apply(params["moe"], flat, args, train=train, rng=rng,
+                           ctx=ctx, mask=mask)
         out = out + y.reshape(b, s, d)
     if kind.ffn in ("dense", "moe+dense"):
         out = out + layers.mlp(params["mlp"], h, cfg.activation, ctx=ctx)

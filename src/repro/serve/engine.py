@@ -171,10 +171,11 @@ class ServeConfig:
     telemetry_keep_last_n: int = 512
     # Fused single-launch MoE decode (docs/kernels.md §Fused decode
     # step): each MoE/MoA layer's decode hot path runs routing + scatter
-    # + expert FFN + combine as ONE kernel launch.  Greedy outputs are
-    # bit-identical on/off (pinned by the serve parity matrix); the
-    # backend falls back per call (RuntimeWarning) when the fused slab
-    # exceeds the VMEM budget.  Decode-only — prefill stays unfused.
+    # + expert FFN + combine as ONE kernel launch.  Outputs match the
+    # unfused path within float tolerance (tests/test_fused_decode.py);
+    # the backend falls back per call (counted, RuntimeWarning) when the
+    # fused slab exceeds the VMEM budget.  Decode-only — prefill stays
+    # unfused.
     fused_decode: bool = False
 
 

@@ -23,24 +23,18 @@ jit a closure and cannot add a traced argument (the serve engine, the test
 harness); it is set at the jit/shard_map boundary, read at trace time, and
 never mutated inside traced code.
 
-Version compatibility
----------------------
-All jax-version probing in the repo lives here (enforced by
-tests/test_version_compat.py).  The pinned jax 0.4.x has no abstract-mesh
-query, no ``jax.set_mesh``, no top-level ``jax.shard_map`` and no
-``axis_types=`` on ``jax.make_mesh``; the shims below degrade gracefully:
-
-* :func:`abstract_mesh_or_none` — ``None`` where the query does not exist,
-* :func:`make_mesh` — drops ``axis_types`` when unsupported,
-* :func:`use_mesh` — no-op context manager when ``jax.set_mesh`` is absent
-  (constraints here are full ``NamedSharding``s, so no ambient mesh is
-  needed),
-* :func:`shard_map` — top-level API when present, else the experimental
-  one with ``auto=`` / ``check_rep=`` spelled for 0.4.x.
+JAX API surface
+---------------
+All direct use of the JAX sharding API surface that has moved between
+releases lives here (enforced by tests/test_version_compat.py), written
+for the installed JAX (0.9): :func:`abstract_mesh_or_none`,
+:func:`make_mesh` (Auto axis types), :func:`use_mesh`, :func:`shard_map`
+(``axis_names=`` / ``check_vma=``), :func:`axis_size` and
+:func:`compiled_cost_analysis`.  Callers use these names, so the next API
+move is a one-file change.
 """
 from __future__ import annotations
 
-import contextlib
 import contextvars
 import dataclasses
 from typing import Sequence
@@ -53,108 +47,57 @@ from repro.sharding import partition
 
 
 # ---------------------------------------------------------------------------
-# jax-version compat shims (the ONLY place the repo probes jax's API surface)
+# JAX API wrappers (the ONLY place the repo touches these symbols)
 # ---------------------------------------------------------------------------
 
 def abstract_mesh_or_none():
-    """The ambient abstract mesh under jit (jax >= 0.5), or ``None``.
-
-    jax 0.4.x has no ``jax.sharding.get_abstract_mesh``; callers treat
-    ``None`` as "no ambient mesh" and fall back to the explicit context.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    try:
-        mesh = get()
-    except Exception:
-        return None
-    if mesh is None or getattr(mesh, "empty", True):
-        return None
-    return mesh
+    """The ambient abstract mesh under jit (``jax.set_mesh``), or ``None``
+    when no mesh is set; callers then fall back to the explicit context."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes), tuple(axis_names), devices=devices,
-                axis_types=(axis_type.Auto,) * len(tuple(axis_names)))
-        except TypeError:
-            pass  # make_mesh predates axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         devices=devices)
+    """``jax.make_mesh`` with every axis Auto (GSPMD places what the
+    explicit constraints leave open)."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(names))
 
 
 def compiled_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version
-    (0.4.x returns a one-element list of dicts, newer returns the dict)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``Compiled.cost_analysis()`` as a flat dict."""
+    return dict(compiled.cost_analysis())
 
 
 def axis_size(axis_name: str) -> int:
-    """Size of a named mapped axis inside shard_map, version-portable.
-
-    ``jax.lax.axis_size`` where it exists; on 0.4.x ``psum(1, axis)``
-    constant-folds to the same Python int."""
-    sz = getattr(jax.lax, "axis_size", None)
-    if sz is not None:
-        return sz(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of a named mapped axis inside shard_map."""
+    return jax.lax.axis_size(axis_name)
 
 
 def use_mesh(mesh: Mesh):
-    """``jax.set_mesh(mesh)`` where it exists, else a no-op context.
-
-    On jax 0.4.x no ambient mesh is needed: every constraint the repo emits
-    is a full ``NamedSharding`` carrying its mesh (see
-    :meth:`MeshContext.with_constraint`)."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return contextlib.nullcontext(mesh)
+    """``jax.set_mesh(mesh)``: the ambient mesh for the enclosed code."""
+    return jax.set_mesh(mesh)
 
 
-# Whether with_sharding_constraint is usable inside a partially-manual
-# shard_map body.  On 0.4.x the partitioner cannot mix a NamedSharding
-# constraint with manual axes, so constraints under manual mode degrade to
-# identity (the in_specs/out_specs still pin the boundary shardings).
-CAN_CONSTRAIN_UNDER_MANUAL = hasattr(jax, "set_mesh")
+# Constraints inside a partially-manual shard_map body are supported by
+# the installed JAX: with_constraint strips the Manual axes and constrains
+# the Auto ones (kept as a name for callers that branch on it).
+CAN_CONSTRAIN_UNDER_MANUAL = True
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, *,
               manual_axes: Sequence[str] | None = None):
-    """Version-portable ``shard_map``.
+    """``jax.shard_map`` without replication checks.
 
     ``manual_axes=None`` means fully manual (every mesh axis).  Otherwise
-    only the named axes are manual and the rest stay Auto for GSPMD —
-    spelled ``axis_names=``/``check_vma=`` on new jax and
-    ``auto=``/``check_rep=`` on 0.4.x.
+    only the named axes are manual and the rest stay Auto for GSPMD.
     """
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        kw = {}
-        if manual_axes is not None:
-            kw["axis_names"] = set(manual_axes)
-        try:
-            return top(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False, **kw)
-        except TypeError:
-            pass  # older top-level signature; fall through
-    from jax.experimental.shard_map import shard_map as _sm
-    # 0.4.x: partial-auto (`auto=`) lowers axis_index to a PartitionId the
-    # old SPMD partitioner rejects, so degrade to fully manual — the
-    # unnamed axes become replicated inside the body (numerics unchanged;
-    # in-body GSPMD placement of those axes is lost, which is why
-    # CAN_CONSTRAIN_UNDER_MANUAL is False here).
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    kw = {} if manual_axes is None else {"axis_names": set(manual_axes)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -261,9 +204,8 @@ class MeshContext:
 
         Off-mesh (``mesh is None`` and no ambient abstract mesh) this is the
         identity — the single-device smoke-test path.  Under a Manual-mode
-        enclosing shard_map on jax 0.4.x, constraints degrade to identity
-        (the partitioner cannot mix NamedSharding constraints with manual
-        axes there); the shard_map's own specs still pin the boundaries.
+        enclosing shard_map the Manual axes are stripped and only the Auto
+        axes are constrained.
         """
         mesh = self.mesh
         if mesh is None:
@@ -274,8 +216,6 @@ class MeshContext:
             partition.resolve_spec(self.rules, mesh, x.shape, logical_axes),
             self.manual_axes)
         if all(e is None for e in spec):
-            return x
-        if self.manual_axes and not CAN_CONSTRAIN_UNDER_MANUAL:
             return x
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, spec))
@@ -307,7 +247,7 @@ def current_ctx() -> MeshContext | None:
 
 def with_constraint(x, logical_axes, ctx: MeshContext | None = None):
     """Explicit-first constraint: use ``ctx`` if given, else the contextvar,
-    else the ambient abstract mesh (jax >= 0.5), else identity."""
+    else the ambient abstract mesh, else identity."""
     ctx = ctx or current_ctx()
     if ctx is None:
         mesh = abstract_mesh_or_none()

@@ -127,9 +127,8 @@ def pipeline_stack_apply(block_params, x_mb, cfg: ModelConfig, *,
 
     def stage_body(params_stage, x, mb_rng):
         # params_stage: [per, ...] one stage's layers; x: [B_mb, S, d]
-        # aux is rank-1 throughout: 0.4.x shard_map lifts closed-over
-        # scalar constants as replicated inputs and its transpose-time
-        # unmentioned-axis psum helper assumes ndim >= 1.
+        # aux is carried rank-1 ([1]) through the layer scan and the
+        # stage boundary; the caller reads element 0.
         aux = jnp.zeros((1,), jnp.float32)
 
         def layer_step(carry, xs):

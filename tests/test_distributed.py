@@ -233,3 +233,56 @@ def test_dryrun_cell_smoke():
         print("CELL_OK")
     """, n_devices=16)
     assert "CELL_OK" in out
+
+
+def test_expert_sharded_serving_matches_single_device():
+    """The serving shard_map schedule (experts over model, expert width
+    over data, every device routing the whole batch) gives the
+    single-device layer's output and telemetry, and the transformer picks
+    it — and the all-to-all schedule for training — only for the pallas
+    backend on a multi-device mesh."""
+    out = _run("""
+        from repro.common import param as pm
+        from repro.core import expert_parallel as ep
+        from repro.core.moe import MoEArgs, moe_apply, moe_defs
+        from repro.core.router import RouterSpec
+        from repro.models import transformer
+        from repro.sharding import context
+        mesh = context.make_mesh((2, 2), ("data", "model"))
+        # capacity 4 per expert for 20 assignments: some are dropped.
+        kw = dict(n_experts=4, k=2, d_model=16, d_ff=32,
+                  activation="swiglu", dtype=jnp.float32,
+                  router=RouterSpec(capacity_factor=0.7,
+                                    capacity_multiple=1),
+                  kernel_backend="pallas")
+        a = MoEArgs(**kw)
+        params = pm.materialize(moe_defs(a), jax.random.PRNGKey(0))
+        params["gate"]["wg"] = 0.5 * jax.random.normal(
+            jax.random.PRNGKey(7), params["gate"]["wg"].shape)
+        x = jax.random.normal(jax.random.PRNGKey(1), (12, 16))
+        mask = jnp.asarray([1.] * 10 + [0.] * 2)
+        want, aux1 = moe_apply(params, x, a, train=False, mask=mask)
+        for plan in ("decode_std", "prefill_tp"):
+            ctx = context.MeshContext.for_mesh(mesh, plan)
+            got, aux = jax.jit(lambda p, x, m: ep.moe_apply_expert_sharded(
+                p, x, a, ctx=ctx, mask=m))(params, x, mask)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-5, atol=1e-6)
+            for key in ("expert_load", "overflow"):
+                np.testing.assert_array_equal(
+                    np.asarray(aux["telemetry"][key]),
+                    np.asarray(aux1["telemetry"][key]))
+        assert float(aux1["telemetry"]["overflow"].sum()) > 0
+
+        ctx = context.MeshContext.for_mesh(mesh, "dp_tp_ep")
+        pick = transformer._moe_schedule
+        assert pick(a, ctx, train=True, n_tokens=8) is ep.moe_apply_ep
+        assert pick(a, ctx, train=True, n_tokens=6) is moe_apply
+        assert pick(a, ctx, train=False, n_tokens=8) not in (
+            moe_apply, ep.moe_apply_ep)
+        ref = MoEArgs(**dict(kw, kernel_backend="ref"))
+        assert pick(ref, ctx, train=True, n_tokens=8) is moe_apply
+        assert pick(a, None, train=True, n_tokens=8) is moe_apply
+        print("SHARDED_SERVE_OK")
+    """, n_devices=4)
+    assert "SHARDED_SERVE_OK" in out

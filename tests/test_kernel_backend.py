@@ -356,12 +356,44 @@ def test_backend_vmem_guard_falls_back_to_ref():
     y_pal, _ = moe_apply(params, x,
                          MoEArgs(**MOE_KW, kernel_backend="pallas"),
                          train=False)
-    y_fb, _ = moe_apply(params, x,
-                        MoEArgs(**MOE_KW, kernel_backend="pallas",
-                                dispatch_vmem_limit=64),
-                        train=False)
+    before = bk_lib.fallbacks()
+    with pytest.warns(RuntimeWarning, match="falling back"):
+        y_fb, _ = moe_apply(params, x,
+                            MoEArgs(**MOE_KW, kernel_backend="pallas",
+                                    dispatch_vmem_limit=64),
+                            train=False)
     np.testing.assert_allclose(np.asarray(y_fb), np.asarray(y_pal),
                                rtol=2e-4, atol=2e-5)
+    after = bk_lib.fallbacks()
+    for site in ("dispatch", "combine"):
+        assert after.get(site, 0) == before.get(site, 0) + 1, site
+
+
+def test_no_fallback_counted_within_budget():
+    params = pm.materialize(moe_defs(MoEArgs(**MOE_KW)),
+                            jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (24, 16))
+    before = bk_lib.fallbacks()
+    moe_apply(params, x, MoEArgs(**MOE_KW, kernel_backend="pallas"),
+              train=False)
+    assert bk_lib.fallbacks() == before
+
+
+def test_interpret_mode_is_chosen_at_call_time_from_the_platform():
+    """None follows the platform (the interpreter off-TPU), explicit
+    values win, and no kernel entry point defaults to interpret=True."""
+    import inspect
+    from repro.kernels import dispatch as dl
+    from repro.kernels import fused_decode as fd
+    from repro.kernels import gmm as gmm_lib
+    from repro.kernels import platform
+    from repro.kernels import topk_gating as topk_lib
+    assert platform.interpret_mode(None) is (jax.default_backend() != "tpu")
+    assert platform.interpret_mode(False) is False
+    for fn in (gmm_lib.gmm, topk_lib.topk_gating, topk_lib.topk_gating_full,
+               dl.dispatch, dl.combine, fd.decode_step, fd.routed_apply):
+        default = inspect.signature(fn).parameters["interpret"].default
+        assert default is None, fn
 
 
 # ---------------------------------------------------------------------------

@@ -75,7 +75,7 @@ def test_combine_guard_shares_backend_estimate():
     x, p = _mk_plan(t, e, k, cap, seed=3, d=d)
     buf = dsp.dispatch(x, p)
     limit = dl.vmem_bytes(e, cap, d, jnp.float32,
-                          min(dl.COMBINE_BLOCK_T, t))
+                          min(dl.COMBINE_BLOCK_T, t), op="combine")
     out = ops.combine(buf, p.weight, p.expert_index, p.position,
                       vmem_limit=limit)     # must not raise at the boundary
     assert out.shape == (t, d)

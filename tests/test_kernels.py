@@ -152,9 +152,17 @@ def test_topk_gating_full_raw_values(extra):
 def test_topk_gating_ties_stable():
     logits = jnp.zeros((8, 16))
     w, idx = ops.topk_gating(logits, 2)
-    # all-equal logits: uniform weights, first indices win (argmax order)
+    # all-equal logits (the zero-initialised gate): uniform weights, the
+    # first indices win — lax.top_k's order
     np.testing.assert_allclose(np.asarray(w), 0.5, rtol=1e-6)
     assert (np.asarray(idx) == np.array([0, 1])).all()
+    # partial ties (values on a coarse grid; + 0.0 turns -0.0, which
+    # lax.top_k orders below +0.0, into +0.0): lax.top_k's order
+    coarse = jnp.round(jax.random.normal(jax.random.PRNGKey(4),
+                                         (64, 16))) + 0.0
+    _, idx = ops.topk_gating(coarse, 8)
+    np.testing.assert_array_equal(np.asarray(idx),
+                                  np.asarray(jax.lax.top_k(coarse, 8)[1]))
 
 
 # ---------------------------------------------------------------------------

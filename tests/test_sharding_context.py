@@ -1,6 +1,6 @@
 """MeshContext subsystem: plan resolution on 1- and 8-device meshes,
-Manual-axis stripping, the contextvar plumbing, and the jax-0.4.x
-no-abstract-mesh fallback (identity constraints off-mesh)."""
+Manual-axis stripping, the contextvar plumbing, and the no-abstract-mesh
+fallback (identity constraints off-mesh)."""
 import os
 import subprocess
 import sys
@@ -98,19 +98,6 @@ def test_manual_axis_stripped_from_specs():
     assert ctx.manual_axes == frozenset()
 
 
-def test_manual_constraint_degrades_on_04x():
-    """Under a Manual-mode context on jax 0.4.x, with_constraint must be
-    the identity (the partitioner cannot mix NamedSharding constraints
-    with manual axes there)."""
-    if context.CAN_CONSTRAIN_UNDER_MANUAL:
-        pytest.skip("new jax: constraints allowed under manual mode")
-    mesh = context.make_mesh((1, 1), ("data", "model"))
-    stage_ctx = context.MeshContext.for_mesh(mesh, "dp_tp_ep").manual(
-        "data")
-    x = jnp.ones((4, 4))
-    assert stage_ctx.with_constraint(x, ("batch", "embed")) is x
-
-
 # ---------------------------------------------------------------------------
 # contextvar plumbing + the no-abstract-mesh fallback
 # ---------------------------------------------------------------------------
@@ -122,10 +109,8 @@ def test_null_context_constraint_is_identity():
 
 
 def test_no_ctx_no_abstract_mesh_is_identity():
-    """jax 0.4.x has no ambient abstract mesh: with no active context the
-    free-function constraint must return its input unchanged (this is the
-    exact seed failure mode — an AttributeError — turned into graceful
-    degradation)."""
+    """With no active context and no ambient abstract mesh the
+    free-function constraint must return its input unchanged."""
     assert context.current_ctx() is None
     x = jnp.ones((4, 4))
     y = context.with_constraint(x, ("batch", "embed"))
@@ -219,9 +204,8 @@ def test_sharded_constraint_matches_unsharded_execution():
 
 
 def test_manual_stripping_on_eight_devices():
-    """shard_map manual over 'data' with an in-body constraint: on 0.4.x
-    the constraint degrades to identity; either way numerics match the
-    unsharded reference."""
+    """shard_map manual over 'data' with an in-body constraint on the
+    Auto axis: numerics match the unsharded reference."""
     out = _run("""
         from jax.sharding import PartitionSpec as P
         from repro.sharding import context
