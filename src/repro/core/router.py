@@ -263,6 +263,7 @@ class Router:
     def capacity(self, n_tokens: int, *, train: bool) -> int:
         return self.spec.capacity(n_tokens, self.n_experts, train=train)
 
+    @jax.named_scope("router")
     def route(self, params, x: jax.Array, *, train: bool,
               rng: jax.Array | None = None,
               mask: jax.Array | None = None,

@@ -18,13 +18,12 @@ with no signal; this registry is the fix).  Selection order:
 ("pallas" -> pallas, anything else -> ref).
 
 Observability: every backend call site (dispatch / expert-FFN GMM /
-combine) runs under an ambient-tracer span (``kernel.dispatch`` /
-``kernel.gmm`` / ``kernel.combine`` with backend + shape attrs,
-``repro.obs.trace.current()``).  These sites execute during ``jax.jit``
-*tracing*, so a recorded span measures trace/staging time at the step
-that triggered compilation — per-call device time lives in the host-side
-step spans (serve/train) that block on results.  With no tracer
-installed the span is the shared no-op (docs/observability.md).
+combine / fused decode) runs under a ``jax.named_scope`` of its name
+(``kernel.dispatch``, ``kernel.gmm``, ``kernel.combine``,
+``kernel.decode_step``, ``kernel.decode_proj``).  A scope is metadata:
+it prefixes the ``op_name`` of the HLO ops the call emits, which the
+device trace shows, and times nothing; the ops keep their instruction
+names (``gmm.N``, ``_dispatch_jit.N``, ...), docs/observability.md.
 
 MeshContext awareness
 ---------------------
@@ -53,7 +52,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import dispatch as dsp
-from repro.obs import trace as trace_lib
 from repro.sharding import context as ctx_lib
 
 log = logging.getLogger(__name__)
@@ -295,8 +293,7 @@ def _decode_proj_via(bk: "KernelBackend", x, w, plan_in, plan_out, a, *,
 # ---------------------------------------------------------------------------
 
 def _ref_expert_ffn(params, x, a, *, ctx=None):
-    with trace_lib.current().span("kernel.gmm", backend="ref",
-                                  shape=tuple(x.shape)):
+    with jax.named_scope("kernel.gmm"):
         w1 = params["w1"].astype(a.dtype)
         w2 = params["w2"].astype(a.dtype)
         h = jnp.einsum("ecd,edf->ecf", x, w1,
@@ -314,8 +311,7 @@ def _ref_expert_ffn(params, x, a, *, ctx=None):
 
 def _ref_dispatch(x, p, a, *, ctx=None):
     p = _as_plan(p)
-    with trace_lib.current().span("kernel.dispatch", backend="ref",
-                                  tokens=int(x.shape[0])):
+    with jax.named_scope("kernel.dispatch"):
         if _dispatch_impl(a) == "einsum":
             return dsp.dispatch_einsum(x, p)
         return dsp.dispatch(x, p)
@@ -323,31 +319,27 @@ def _ref_dispatch(x, p, a, *, ctx=None):
 
 def _ref_combine(buf, p, a, *, dtype=None, ctx=None):
     p = _as_plan(p)
-    with trace_lib.current().span("kernel.combine", backend="ref",
-                                  shape=tuple(buf.shape)):
+    with jax.named_scope("kernel.combine"):
         if _dispatch_impl(a) == "einsum":
             return dsp.combine_einsum(buf, p, dtype=dtype)
         return dsp.combine(buf, p, dtype=dtype)
 
 
 def _ref_gmm(x, w, a, *, ctx=None):
-    with trace_lib.current().span("kernel.gmm", backend="ref",
-                                  shape=tuple(x.shape)):
+    with jax.named_scope("kernel.gmm"):
         return jnp.einsum(
             "eck,ekn->ecn", x, w.astype(x.dtype),
             preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def _ref_decode_step(params, x, a, *, mask=None, ctx=None):
-    with trace_lib.current().span("kernel.decode_step", backend="ref",
-                                  tokens=int(x.shape[0])):
+    with jax.named_scope("kernel.decode_step"):
         return _decode_step_via(get("ref"), params, x, a, mask=mask,
                                 ctx=ctx)
 
 
 def _ref_decode_proj(x, w, plan_in, plan_out, a, *, dtype=None, ctx=None):
-    with trace_lib.current().span("kernel.decode_proj", backend="ref",
-                                  tokens=int(x.shape[0])):
+    with jax.named_scope("kernel.decode_proj"):
         return _decode_proj_via(get("ref"), x, w, plan_in, plan_out, a,
                                 dtype=dtype, ctx=ctx)
 
@@ -408,8 +400,7 @@ def _register_pallas() -> None:
             from repro.kernels import gmm as gmm_lib
             tiles = dict(bm=gmm_lib.DEFAULT_TILE, bn=gmm_lib.DEFAULT_TILE,
                          bk=gmm_lib.DEFAULT_TILE)
-        with trace_lib.current().span("kernel.gmm", backend="pallas",
-                                      shape=tuple(x.shape)):
+        with jax.named_scope("kernel.gmm"):
             return ops.expert_ffn(params, x, activation=a.activation,
                                   **tiles)
 
@@ -421,8 +412,7 @@ def _register_pallas() -> None:
         ok, e_block = _plan_e_block(a, p.n_experts, p.capacity,
                                     x.shape[-1], x.dtype, x.shape[0],
                                     "dispatch")
-        with trace_lib.current().span("kernel.dispatch", backend="pallas",
-                                      tokens=int(x.shape[0]), fused=ok):
+        with jax.named_scope("kernel.dispatch"):
             if not ok:
                 return dsp.dispatch(x, p)
             return ops.dispatch(x, p.expert_index, p.position,
@@ -440,8 +430,7 @@ def _register_pallas() -> None:
         ok, e_block = _plan_e_block(a, buf.shape[0], buf.shape[1],
                                     buf.shape[2], buf.dtype, n_tok,
                                     "combine")
-        with trace_lib.current().span("kernel.combine", backend="pallas",
-                                      shape=tuple(buf.shape), fused=ok):
+        with jax.named_scope("kernel.combine"):
             if not ok:
                 return dsp.combine(buf, p, dtype=dtype)
             return ops.combine(buf, p.weight, p.expert_index, p.position,
@@ -480,8 +469,7 @@ def _register_pallas() -> None:
         capacity = spec.capacity(t, e, train=False)
         gated = a.activation == "swiglu"
         wdt = params["w1"].dtype
-        with trace_lib.current().span("kernel.decode_step",
-                                      backend="pallas", tokens=int(t)):
+        with jax.named_scope("kernel.decode_step"):
             if spec.policy == "noisy_topk" and not spec.priority_dispatch:
                 # Full fusion: eval routing is the deterministic clean-
                 # logit top-k, computed in-kernel alongside everything
@@ -524,9 +512,7 @@ def _register_pallas() -> None:
         from repro.kernels import fused_decode as fused_lib
         p_in = _as_plan(plan_in)
         p_out = _as_plan(plan_out)
-        with trace_lib.current().span("kernel.decode_proj",
-                                      backend="pallas",
-                                      tokens=int(x.shape[0])):
+        with jax.named_scope("kernel.decode_proj"):
             need = fused_lib.routed_vmem_bytes(
                 x.shape[0], x.shape[-1], w.shape[-1], 0, p_in.n_experts,
                 p_in.capacity, x.dtype, w.dtype, mode="proj")
@@ -544,8 +530,7 @@ def _register_pallas() -> None:
             from repro.kernels import gmm as gmm_lib
             tiles = dict(bm=gmm_lib.DEFAULT_TILE, bn=gmm_lib.DEFAULT_TILE,
                          bk=gmm_lib.DEFAULT_TILE)
-        with trace_lib.current().span("kernel.gmm", backend="pallas",
-                                      shape=tuple(x.shape)):
+        with jax.named_scope("kernel.gmm"):
             return ops.gmm(x, w.astype(x.dtype), activation="none",
                            **tiles)
 

@@ -11,6 +11,7 @@ Example:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import numpy as np
@@ -92,14 +93,15 @@ def main():
                          "combine; outputs match the unfused path within "
                          "float tolerance — docs/kernels.md §Fused "
                          "decode step)")
-    ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="write a chrome-trace JSON of the run here "
-                         "(Perfetto-loadable; docs/observability.md)")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="write one profiler trace of the run under DIR: "
+                         "device ops and the engine's serve.* spans on "
+                         "one clock (.xplane.pb, and perfetto_trace.json.gz "
+                         "for Perfetto; docs/observability.md)")
     ap.add_argument("--trace-sync", action="store_true",
                     help="calibration tracing: block on device results "
                          "inside prefill/decode spans so durations are "
-                         "real op walls (costs ~2%% lost overlap; what "
-                         "the cost-model fit wants)")
+                         "real op walls (costs ~2%% lost overlap)")
     ap.add_argument("--log-decisions", action="store_true",
                     help="record per-step scheduler StepDecision entries "
                          "(the replay simulator's fidelity contract)")
@@ -158,7 +160,6 @@ def main():
         prefix_cache=args.prefix_cache,
         prefix_cache_bytes=args.prefix_cache_bytes,
         fused_decode=args.fused_decode,
-        trace_path=args.trace,
         trace_sync=args.trace_sync,
         log_decisions=args.log_decisions), ctx=ctx)
     rng = np.random.RandomState(0)
@@ -171,7 +172,9 @@ def main():
                 args.new_tokens, arrival=i * args.stagger)
             for i in range(args.requests)]
     t0 = time.perf_counter()
-    engine.run()
+    with (jax.profiler.trace(args.trace, create_perfetto_trace=True)
+          if args.trace else contextlib.nullcontext()):
+        engine.run()
     dt = time.perf_counter() - t0
     total = engine.stats["generated_tokens"]
     print(f"[serve] {args.requests} requests x {args.new_tokens} tokens in "
@@ -215,8 +218,8 @@ def main():
                   f"{load.astype(int).tolist()} "
                   f"(capacity overflow: {over:.0f})")
     if args.trace:
-        print(f"[serve] trace written: {args.trace} "
-              f"({len(engine.tracer.events)} events; load in Perfetto)")
+        print(f"[serve] profiler trace written under {args.trace} "
+              "(perfetto_trace.json.gz loads in Perfetto)")
     if args.log_decisions:
         print(f"[serve] decision log: {len(engine.sched.decision_log)} "
               "scheduling steps recorded")

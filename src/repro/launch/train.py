@@ -13,6 +13,7 @@ Example:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import tempfile
 
@@ -92,9 +93,11 @@ def main():
                          "moa_positions; docs/moa.md)")
     ap.add_argument("--workdir", default=os.path.join(
         tempfile.gettempdir(), "repro_train"))
-    ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="write a chrome-trace JSON of the run here "
-                         "(train.step spans; docs/observability.md)")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="write one profiler trace of the run under DIR: "
+                         "device ops and the train.* spans on one clock "
+                         "(.xplane.pb, and perfetto_trace.json.gz for "
+                         "Perfetto; docs/observability.md)")
     args = ap.parse_args()
     print(f"[train] compile cache: {enable_compile_cache()}")
 
@@ -156,12 +159,13 @@ def main():
                              checkpoint_every=args.checkpoint_every,
                              log_every=10),
         data_iter=DataIterator(dc), workdir=args.workdir,
-        kernel_backend=cfg.kernel_backend, router=cfg.router,
-        trace_path=args.trace)
-    final = trainer.run()
+        kernel_backend=cfg.kernel_backend, router=cfg.router)
+    with (jax.profiler.trace(args.trace, create_perfetto_trace=True)
+          if args.trace else contextlib.nullcontext()):
+        final = trainer.run()
     if args.trace:
-        print(f"[train] trace written: {args.trace} "
-              f"({len(trainer.tracer.events)} events; load in Perfetto)")
+        print(f"[train] profiler trace written under {args.trace} "
+              "(perfetto_trace.json.gz loads in Perfetto)")
     print(f"[train] done: {final}")
 
 

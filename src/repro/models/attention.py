@@ -343,6 +343,7 @@ def _flash_bwd(causal, window, q_block, kv_block, res, dout):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+@jax.named_scope("attention")
 def attention(params, x, positions, *, rope_theta: float, qk_norm: bool,
               window: int = 0, q_block: int = 512,
               kv_block: int = 512, pad_heads: int = 0,
@@ -384,6 +385,7 @@ def attention(params, x, positions, *, rope_theta: float, qk_norm: bool,
                       preferred_element_type=jnp.float32).astype(dt)
 
 
+@jax.named_scope("attention")
 def prefill_attention(params, x, positions, *, rope_theta: float,
                       qk_norm: bool, cache: dict, window: int = 0,
                       q_block: int = 512, kv_block: int = 512,
@@ -412,10 +414,11 @@ def prefill_attention(params, x, positions, *, rope_theta: float,
             raise ValueError(
                 "chunked prefill is unsupported for sliding-window layers")
         off = int(offset)
-        new_k = jax.lax.dynamic_update_slice_in_dim(cache["k"], kc, off,
-                                                    axis=1)
-        new_v = jax.lax.dynamic_update_slice_in_dim(cache["v"], vc, off,
-                                                    axis=1)
+        with jax.named_scope("kv_update"):
+            new_k = jax.lax.dynamic_update_slice_in_dim(cache["k"], kc, off,
+                                                        axis=1)
+            new_v = jax.lax.dynamic_update_slice_in_dim(cache["v"], vc, off,
+                                                        axis=1)
         # Attend over [cached prefix, this chunk]: the prefix holds the
         # previous chunks' K/V (cast back to compute dtype), the shifted
         # causal mask keeps each row at its absolute position.
@@ -434,16 +437,17 @@ def prefill_attention(params, x, positions, *, rope_theta: float,
         return y, {"k": new_k, "v": new_v}
     o = blockwise_attention(q, k, v, causal=True, window=window,
                             q_block=q_block, kv_block=kv_block)
-    if window > 0 and s >= length:
-        tail = jnp.arange(s - length, s)
-        slots = tail % length
-        new_k = cache["k"].at[:, slots].set(kc[:, tail])
-        new_v = cache["v"].at[:, slots].set(vc[:, tail])
-    else:
-        new_k = jax.lax.dynamic_update_slice_in_dim(cache["k"], kc, 0,
-                                                    axis=1)
-        new_v = jax.lax.dynamic_update_slice_in_dim(cache["v"], vc, 0,
-                                                    axis=1)
+    with jax.named_scope("kv_update"):
+        if window > 0 and s >= length:
+            tail = jnp.arange(s - length, s)
+            slots = tail % length
+            new_k = cache["k"].at[:, slots].set(kc[:, tail])
+            new_v = cache["v"].at[:, slots].set(vc[:, tail])
+        else:
+            new_k = jax.lax.dynamic_update_slice_in_dim(cache["k"], kc, 0,
+                                                        axis=1)
+            new_v = jax.lax.dynamic_update_slice_in_dim(cache["v"], vc, 0,
+                                                        axis=1)
     dt = x.dtype
     y = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(dt),
                    preferred_element_type=jnp.float32).astype(dt)
@@ -464,6 +468,7 @@ def init_cache_defs(batch: int, max_len: int, n_kv_heads: int, head_dim: int,
             "v": ParamDef(shape, axes, init="zeros", dtype=dtype)}
 
 
+@jax.named_scope("attention")
 def decode_attention(params, x, cache, cur_index, *, rope_theta: float,
                      qk_norm: bool, window: int = 0) -> tuple[jax.Array, dict]:
     """One-token decode. x: [B, 1, d]; cur_index: scalar position, or a
@@ -485,9 +490,10 @@ def decode_attention(params, x, cache, cur_index, *, rope_theta: float,
     # offset on the sharded cache-sequence axis makes GSPMD all-gather the
     # whole cache per layer; the blend is shard-local (each shard compares
     # its own slot ids) and costs one select over data already streamed.
-    hit = (jnp.arange(length)[None, :] == slot[:, None])[..., None, None]
-    k = jnp.where(hit, k_new.astype(cache["k"].dtype), cache["k"])
-    v = jnp.where(hit, v_new.astype(cache["v"].dtype), cache["v"])
+    with jax.named_scope("kv_update"):
+        hit = (jnp.arange(length)[None, :] == slot[:, None])[..., None, None]
+        k = jnp.where(hit, k_new.astype(cache["k"].dtype), cache["k"])
+        v = jnp.where(hit, v_new.astype(cache["v"].dtype), cache["v"])
 
     h, hd = q.shape[2], q.shape[3]
     kv_heads = k.shape[2]

@@ -2,9 +2,9 @@
 
 Three layers, each usable on its own:
 
-* :mod:`repro.obs.trace` — chrome-trace span capture (Perfetto /
-  ``chrome://tracing`` loadable JSON) with a null tracer so untraced hot
-  paths pay a single attribute read;
+* :mod:`repro.obs.trace` — spans as ``jax.profiler`` annotations, on
+  the device trace's clock under a profiler session, and an optional
+  in-memory chrome-trace recorder (Perfetto-loadable JSON);
 * :mod:`repro.obs.metrics` — typed Counter/Gauge/Histogram instruments in
   a :class:`~repro.obs.metrics.MetricsRegistry` (bounded memory,
   p50/p95/p99 from fixed buckets);
@@ -13,7 +13,7 @@ Three layers, each usable on its own:
   scheduler stack against simulated step costs (imported lazily — it
   pulls in the serve stack; ``import repro.obs.replay`` explicitly).
 
-Only the dependency-free layers are imported eagerly so low-level modules
-(kernels, models) can import ``repro.obs.trace`` without cycles.
+Only the layers that import nothing of the program are imported eagerly,
+so any module can import ``repro.obs.trace`` without cycles.
 """
 from repro.obs import metrics, trace  # noqa: F401

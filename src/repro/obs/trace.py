@@ -1,32 +1,29 @@
-"""Chrome-trace span capture for the serve engine, trainer and kernels.
+"""Spans for the serve engine and trainer, on the profiler's clock.
 
-A :class:`Tracer` records *complete* span events (``ph="X"``), counter
-series (``ph="C"``) and instants (``ph="i"``) in the chrome trace-event
-format — the emitted JSON loads directly in Perfetto or
-``chrome://tracing``.  Timestamps come from ``time.perf_counter_ns``
-relative to the tracer's epoch, reported in microseconds (the format's
-native unit).
+Every span is a ``jax.profiler.TraceAnnotation``: while a profiler
+session runs (``jax.profiler.trace`` / ``start_trace``), it lands on the
+host plane of the same trace as the device ops, with its attributes as
+event stats, so device idle time can be put down to the host work that
+was running.  With no session ``NULL.span`` returns a shared no-op
+context manager after one check of the profiler's state, so untraced
+hot paths allocate nothing (docs/observability.md §Overhead discipline).
 
-Overhead discipline (docs/observability.md): the *off* path is one
-attribute read plus a no-op context manager —
-
-    tr = trace.current()            # module-level, defaults to NULL
+    tr = engine.tracer              # trace.NULL unless trace_path is set
     with tr.span("serve.decode", active=n):
         ...
 
-``NULL.span`` returns a shared singleton whose ``__enter__``/``__exit__``
-do nothing, so call sites need no ``if tracing:`` guards.  The *on* path
-is two ``perf_counter_ns`` reads and one tuple append per span — the
-chrome event dicts are materialized lazily by :attr:`Tracer.events` /
-:meth:`Tracer.save`, never while the workload runs.
+Call sites need no ``if tracing:`` guards.  The profiler trace is the one
+on the device clock; read it with ``jax.profiler.ProfileData``.
 
-Instrumented code reads the ambient tracer via :func:`current`; owners
-(``ServeEngine``, ``Trainer``) install theirs for the duration of a step
-with :func:`use`.  Spans recorded inside ``jax.jit`` *tracing* (e.g. the
-kernel backend's dispatch/gmm/combine call sites) measure trace/compile
-time at the step that triggered compilation — per-call device time lives
-in the host-side step spans that block on results; both are real wall
-time a serve step paid.
+Library code that has no tracer of its own reads the ambient one via
+:func:`current`; owners (``ServeEngine``, ``Trainer``) install theirs for
+the duration of a step with :func:`use`.
+
+A :class:`Tracer` also keeps each span in memory in the chrome
+trace-event format (``ph="X"`` spans, ``ph="C"`` counters, ``ph="i"``
+instants), timed by ``time.perf_counter_ns`` on the host alone, and saves
+them as JSON that Perfetto loads.  The cost-model fit
+(``benchmarks/fit_costs.py``, ``repro.obs.replay``) reads that file.
 
 Attr values must be JSON-serializable; numpy scalars are coerced on save.
 """
@@ -37,9 +34,14 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
+_profiling = TraceAnnotation.is_enabled
+
 
 class _NullSpan:
-    """Shared no-op context manager: the entire cost of tracing-off."""
+    """Shared no-op context manager: a span's cost with no profiler
+    session and no recorder."""
 
     __slots__ = ()
 
@@ -54,11 +56,15 @@ _NULL_SPAN = _NullSpan()
 
 
 class NullTracer:
-    """Tracer-shaped no-op; ``trace.NULL`` is the ambient default."""
+    """``trace.NULL``: spans go to the profiler alone (a profiler
+    annotation while a session runs, else the shared no-op), and nothing
+    is kept in memory."""
 
     enabled = False
 
     def span(self, name, **attrs):
+        if _profiling():
+            return TraceAnnotation(name, **attrs)
         return _NULL_SPAN
 
     def instant(self, name, **attrs):
@@ -87,23 +93,27 @@ _ident = threading.get_ident
 
 
 class _Span:
-    """One live span: appends a raw ``(name, t0, t1, tid, attrs)`` tuple
-    on exit; the ``X`` (complete) event dict is built at save time."""
+    """One live span: a profiler annotation that also appends a raw
+    ``(name, t0, t1, tid, attrs)`` tuple on exit; the ``X`` (complete)
+    event dict is built at save time."""
 
-    __slots__ = ("_events", "_name", "_attrs", "_t0")
+    __slots__ = ("_events", "_name", "_attrs", "_t0", "_ann")
 
     def __init__(self, events, name, attrs):
         self._events = events
         self._name = name
         self._attrs = attrs
+        self._ann = TraceAnnotation(name, **attrs)
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = _perf_ns()
         return self
 
     def __exit__(self, et, ev, tb):
         self._events.append(
             (self._name, self._t0, _perf_ns(), _ident(), self._attrs))
+        self._ann.__exit__(et, ev, tb)
         return False
 
 
@@ -125,7 +135,8 @@ def _jsonable(v):
 
 
 class Tracer:
-    """Chrome-trace event recorder.
+    """Chrome-trace event recorder, in memory on the host clock (each
+    span is a profiler annotation as well).
 
     ``path`` is where :meth:`save` writes by default (the owner decides
     when — e.g. ``ServeEngine.run`` saves at trace end).  Events

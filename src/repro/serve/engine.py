@@ -41,12 +41,18 @@ Observability (docs/observability.md): the engine's bookkeeping lives in
 a typed ``MetricsRegistry`` (``engine.metrics``; the legacy ``.stats``
 dict is a property view over it), per-step MoE expert load / overflow
 aggregates into bounded histogram/counter instruments plus a
-``keep_last_n`` ring of raw entries (``engine.telemetry``), and — with
-``ServeConfig.trace_path`` set — every step emits chrome-trace spans
-(admission, prefix probe/hit, chunk-group prefills with [G, C] attrs,
-blend, reshard, decode, sample, retire) that load in Perfetto and feed
-the cost-model replay simulator (``repro.obs.replay``).  Tracing off is
-the default and costs one no-op context manager per span site.
+``keep_last_n`` ring of raw entries (``engine.telemetry``).  Every step
+is a ``serve.step`` span whose named children cover its host path
+(schedule and admission, input building, prefill calls, decode, the
+device->host sync of sampling, telemetry, token append and retirement,
+KV insert, prefix probe/hit).  Spans are profiler annotations: under
+``jax.profiler.trace`` they share a clock with the device ops, so device
+idle time can be put down to the host work that ran in it.  The jitted
+programs carry names (``jit_prefill``, ``jit_prefill_chunk``,
+``jit_decode_step``, ``jit_sample_argmax``) that the device trace shows.
+With ``ServeConfig.trace_path`` set the spans are also kept as a chrome
+trace, which feeds the cost-model replay simulator
+(``repro.obs.replay``).
 
 Batching-invariance caveat: all pool slots (active *and* dead) share the
 MoE capacity buffers of one fused decode, so greedy outputs are
@@ -60,6 +66,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -144,16 +151,18 @@ class ServeConfig:
     # Accounting charges the full per-page byte size for every entry;
     # pinned entries (in-flight prefills) are never evicted.
     prefix_cache_bytes: int = 1 << 30
-    # Chrome-trace span capture (docs/observability.md): when set, every
-    # engine step records spans (schedule, prefix probe/hit, chunk-group
-    # prefill with [G, C] attrs, blend, reshard, decode, sample, retire)
-    # and ``run()`` writes a Perfetto-loadable trace here.  None (the
-    # default) installs the null tracer: the hot path pays one no-op
-    # context manager per span site and outputs stay bit-identical.
+    # Chrome-trace span capture (docs/observability.md): when set, the
+    # engine's spans (schedule, prefix probe/hit, chunk-group prefill
+    # with [G, C] attrs, reshard, decode, sample, retire, ...) are also
+    # kept in memory on the host clock and ``run()`` writes them here as
+    # a Perfetto-loadable trace, the input of the cost-model fit.  None
+    # (the default): spans are profiler annotations alone, recorded only
+    # under ``jax.profiler.trace``; outputs are bit-identical either way.
     trace_path: str | None = None
     # Calibration tracing: block on device results *inside* the prefill/
-    # decode spans so each span's duration is that op's real wall (what
-    # the replay cost model fits on — ``make fit-costs`` sets this).
+    # decode spans so each span's duration is that op's real wall, in a
+    # profiler trace and in the chrome trace alike (the replay cost model
+    # fits on the latter — ``make fit-costs`` sets this).
     # Off (the default), spans record dispatch time and device time
     # drains at the step's natural sync points: the trace stays accurate
     # at step granularity and the capture overhead is the span appends
@@ -190,11 +199,11 @@ class ServeEngine:
         self.params = params
         self.cfg = cfg
         self.sc = sc
-        # Tracing off => the shared null tracer: every span site below
-        # costs one attribute read + a no-op context manager.
+        # No trace_path => the null tracer: every span below is a profiler
+        # annotation under a profiler session, else one shared no-op.
         self.tracer = (trace_lib.Tracer(sc.trace_path, process_name="serve")
                        if sc.trace_path else trace_lib.NULL)
-        self._trace_sync = self.tracer.enabled and sc.trace_sync
+        self._trace_sync = sc.trace_sync
         self.ctx = ctx or ctx_lib.MeshContext.null(plan=sc.decode_plan)
         on_mesh = self.ctx.mesh is not None
         self.decode_ctx = (self.ctx.with_plan(sc.decode_plan) if on_mesh
@@ -273,26 +282,33 @@ class ServeEngine:
                     "(docs/serving.md)", RuntimeWarning, stacklevel=2)
             else:
                 self._prefix_on = True
-        self._prefill = jax.jit(
-            lambda p, b, c, li, v: lm.lm_prefill(p, b, c, cfg,
-                                                 ctx=self.prefill_ctx,
-                                                 last_index=li, valid=v))
+        # jit names each program after its function: the device trace
+        # shows jit_prefill(...), jit_decode_step(...) and the others.
+        def prefill(p, b, c, li, v):
+            return lm.lm_prefill(p, b, c, cfg, ctx=self.prefill_ctx,
+                                 last_index=li, valid=v)
+
+        def decode_step(p, t, c, i, v):
+            return lm.lm_decode(p, t, c, i, cfg, ctx=self.decode_ctx,
+                                valid=v, return_telemetry=True)
+
+        def sample_argmax(logits):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        def sample_categorical(keys, logits):
+            return jax.vmap(lambda key, l: jax.random.categorical(
+                key, l / sc.temperature).astype(jnp.int32))(keys, logits)
+
+        self._prefill = jax.jit(prefill)
         # One jitted chunk function per chunk *offset* (chunk length is
         # fixed, so compile count is O(max_len / prefill_chunk)); the
         # static offset keeps the blockwise kv ranges pruned above the
         # shifted diagonal.
         self._chunk_fns: dict[int, object] = {}
-        self._decode = jax.jit(
-            lambda p, t, c, i, v: lm.lm_decode(p, t, c, i, cfg,
-                                               ctx=self.decode_ctx,
-                                               valid=v,
-                                               return_telemetry=True))
-        self._argmax = jax.jit(lambda l: jnp.argmax(l, axis=-1)
-                               .astype(jnp.int32))
+        self._decode = jax.jit(decode_step)
+        self._argmax = jax.jit(sample_argmax)
         if sc.temperature > 0.0:
-            self._categorical = jax.jit(jax.vmap(
-                lambda key, l: jax.random.categorical(
-                    key, l / sc.temperature).astype(jnp.int32)))
+            self._categorical = jax.jit(sample_categorical)
         self.reset()
 
     # -- lifecycle --------------------------------------------------------
@@ -312,6 +328,8 @@ class ServeEngine:
         # so LRU accounting is a multiple of one constant.
         self.prefix: PrefixCache | None = None
         self._pins: dict[int, object] = {}   # rid -> pinned trie entry
+        # rid -> host clock at submit(), popped when the request admits
+        self._submitted_at: dict[int, float] = {}
         if self._prefix_on:
             page_bytes = sum(
                 int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
@@ -326,7 +344,7 @@ class ServeEngine:
             prefill_chunk=self._chunk,
             prefill_budget=self.sc.prefill_budget,
             prefix_probe=self._prefix_probe if self._prefix_on else None,
-            on_admit=self._on_admit if self._prefix_on else None)
+            on_admit=self._on_admit)
         if self.sc.log_decisions:
             self.sched.decision_log = []
         self.step_count = 0
@@ -400,6 +418,7 @@ class ServeEngine:
                     "page — raise max_len or lower prefill_chunk")
         req = Request(rid=self._rid, prompt=prompt,
                       max_new_tokens=max_new_tokens, arrival=arrival)
+        self._submitted_at[req.rid] = time.perf_counter()
         self._rid += 1
         self.queue.push(req)
         return req
@@ -413,13 +432,17 @@ class ServeEngine:
             len(req.tokens))
 
     def _sample_rows(self, logits, reqs: list[Request | None]) -> np.ndarray:
-        """logits: [B, V] -> [B] int32 (row i sampled for reqs[i])."""
+        """logits: [B, V] -> [B] int32 (row i sampled for reqs[i]).  The
+        host waits for the device in ``serve.sync``."""
         if self.sc.temperature <= 0.0:
-            return np.asarray(self._argmax(logits))
-        keys = jnp.stack([
-            self._req_key(r) if r is not None
-            else jax.random.PRNGKey(0) for r in reqs])
-        return np.asarray(self._categorical(keys, logits))
+            ids = self._argmax(logits)
+        else:
+            keys = jnp.stack([
+                self._req_key(r) if r is not None
+                else jax.random.PRNGKey(0) for r in reqs])
+            ids = self._categorical(keys, logits)
+        with self.tracer.span("serve.sync", rows=len(reqs)):
+            return np.asarray(ids)
 
     # -- the step loop ----------------------------------------------------
     def _append_token(self, req: Request, tok: int, slot: int) -> None:
@@ -474,19 +497,20 @@ class ServeEngine:
         capacity; see docs/serving.md)."""
         plen = req.prompt_len
         blen = self._bucket_len(plen)
-        padded = np.zeros((blen,), np.int32)
-        padded[:plen] = req.prompt
-        valid = np.zeros((1, blen), np.float32)
-        valid[0, :plen] = 1.0
-        self.prefill_lengths.add(blen)
-        tokens = jnp.asarray(padded, jnp.int32)[None, :]
         tr = self.tracer
+        with tr.span("serve.inputs", rows=1, tokens=blen):
+            padded = np.zeros((blen,), np.int32)
+            padded[:plen] = req.prompt
+            valid = np.zeros((1, blen), np.float32)
+            valid[0, :plen] = 1.0
+            tokens = jnp.asarray(padded, jnp.int32)[None, :]
+            last = jnp.asarray(plen - 1, jnp.int32)
+            valid = jnp.asarray(valid)
+        self.prefill_lengths.add(blen)
         with tr.span("serve.prefill", rid=req.rid, slot=slot, plen=plen,
                      tokens=blen):
             logits, page = self._prefill(self.params, {"tokens": tokens},
-                                         self._blank_page,
-                                         jnp.asarray(plen - 1, jnp.int32),
-                                         jnp.asarray(valid))
+                                         self._blank_page, last, valid)
             if self._trace_sync:
                 logits = jax.block_until_ready(logits)
         if self.ctx.mesh is not None:
@@ -514,11 +538,21 @@ class ServeEngine:
             return self.prefix.probe(req.prompt)
 
     def _on_admit(self, slot: int, req: Request) -> None:
-        """Scheduler hook, fired the moment a request claims a slot:
-        alias the longest cached block-aligned prefix page into the slot
-        (staged, exactly like a partial chunked-prefill page) and advance
-        ``prefill_pos`` so chunk planning covers only the tail.  The trie
-        entry stays pinned until the prefill completes."""
+        """Scheduler hook, fired the moment a request claims a slot: a
+        ``serve.admit`` span with the time the request waited since
+        ``submit()``, and the prefix-cache lookup when the cache is on."""
+        t = self._submitted_at.pop(req.rid, None)
+        wait = ({} if t is None
+                else {"wait_ms": (time.perf_counter() - t) * 1e3})
+        with self.tracer.span("serve.admit", rid=req.rid, slot=slot, **wait):
+            if self._prefix_on:
+                self._prefix_admit(slot, req)
+
+    def _prefix_admit(self, slot: int, req: Request) -> None:
+        """Alias the longest cached block-aligned prefix page into the
+        slot (staged, exactly like a partial chunked-prefill page) and
+        advance ``prefill_pos`` so chunk planning covers only the tail.
+        The trie entry stays pinned until the prefill completes."""
         hit, page, entry = self.prefix.lookup(req.prompt)
         if hit <= 0:
             return
@@ -540,9 +574,10 @@ class ServeEngine:
         batch shape, so grouped calls of different widths coexist."""
         fn = self._chunk_fns.get(off)
         if fn is None:
-            fn = jax.jit(lambda p, b, c, li, v, _o=off: lm.lm_prefill(
-                p, b, c, self.cfg, ctx=self.prefill_ctx, last_index=li,
-                valid=v, start_pos=_o))
+            def prefill_chunk(p, b, c, li, v):
+                return lm.lm_prefill(p, b, c, self.cfg, ctx=self.prefill_ctx,
+                                     last_index=li, valid=v, start_pos=off)
+            fn = jax.jit(prefill_chunk)
             self._chunk_fns[off] = fn
         return fn
 
@@ -579,33 +614,35 @@ class ServeEngine:
         c = self._chunk
         g = len(group)
         gp = 1 << (g - 1).bit_length()          # power-of-two batch bucket
-        tokens = np.zeros((gp, c), np.int32)
-        valid = np.zeros((gp, c), np.float32)
-        li = np.full((gp,), c - 1, np.int32)    # pad rows: clamped, unread
-        pages = []
-        for i, (slot, w) in enumerate(group):
-            req = w.req
-            tokens[i, :w.length] = req.prompt[w.start:w.start + w.length]
-            valid[i, :w.length] = 1.0
-            # Chunk-local index of the final prompt token (only read on a
-            # row's last chunk; clamped elsewhere).
-            li[i] = min(req.prompt_len - 1 - off, c - 1)
-            pages.append(self._resume_page(slot))
-        pages.extend([self._blank_page] * (gp - g))
-        page_in = pages[0] if gp == 1 else self.kv.stack_pages(pages)
-        self.chunk_offsets.add(off)
         tr = self.tracer
+        with tr.span("serve.inputs", rows=gp, tokens=gp * c):
+            tokens = np.zeros((gp, c), np.int32)
+            valid = np.zeros((gp, c), np.float32)
+            li = np.full((gp,), c - 1, np.int32)  # pad rows: clamped, unread
+            pages = []
+            for i, (slot, w) in enumerate(group):
+                req = w.req
+                tokens[i, :w.length] = req.prompt[w.start:w.start + w.length]
+                valid[i, :w.length] = 1.0
+                # Chunk-local index of the final prompt token (only read
+                # on a row's last chunk; clamped elsewhere).
+                li[i] = min(req.prompt_len - 1 - off, c - 1)
+                pages.append(self._resume_page(slot))
+            pages.extend([self._blank_page] * (gp - g))
+            page_in = pages[0] if gp == 1 else self.kv.stack_pages(pages)
+            tokens, li, valid = (jnp.asarray(tokens), jnp.asarray(li),
+                                 jnp.asarray(valid))
+        self.chunk_offsets.add(off)
         with tr.span("serve.prefill_chunk", offset=off, G=g, Gp=gp, C=c,
                      tokens=gp * c):
             logits, page_out = self._chunk_fn(off)(
-                self.params, {"tokens": jnp.asarray(tokens)}, page_in,
-                jnp.asarray(li), jnp.asarray(valid))
+                self.params, {"tokens": tokens}, page_in, li, valid)
             if self._trace_sync:
                 logits = jax.block_until_ready(logits)
+            out_pages = ([page_out] if gp == 1
+                         else self.kv.split_pages(page_out, g))
         self._c["prefill_calls"].inc()
         self._c["prefill_chunks"].inc(g)
-        out_pages = ([page_out] if gp == 1
-                     else self.kv.split_pages(page_out, g))
         rows: list[Request | None] = [None] * gp
         done_rows = []
         for i, (slot, w) in enumerate(group):
@@ -667,36 +704,40 @@ class ServeEngine:
         active = self.sched.decoding()
         if active:
             n = self.sc.n_slots
-            toks = np.zeros((n,), np.int32)
-            pos = np.zeros((n,), np.int32)
-            occ = np.zeros((n,), np.float32)
-            rows: list[Request | None] = [None] * n
-            for slot, req in active:
-                toks[slot] = req.tokens[-1]
-                # position of the token being fed (the one just sampled).
-                pos[slot] = req.prompt_len + len(req.tokens) - 1
-                occ[slot] = 1.0
-                rows[slot] = req
-            # Slot-occupancy mask: dead slots are masked out of MoE
-            # routing so they stop consuming expert capacity (ROADMAP).
-            if not self.sc.mask_dead_slots:
-                occ[:] = 1.0
+            with tr.span("serve.inputs", rows=n):
+                toks = np.zeros((n,), np.int32)
+                pos = np.zeros((n,), np.int32)
+                occ = np.zeros((n,), np.float32)
+                rows: list[Request | None] = [None] * n
+                for slot, req in active:
+                    toks[slot] = req.tokens[-1]
+                    # position of the token being fed (the one just
+                    # sampled).
+                    pos[slot] = req.prompt_len + len(req.tokens) - 1
+                    occ[slot] = 1.0
+                    rows[slot] = req
+                # Slot-occupancy mask: dead slots are masked out of MoE
+                # routing so they stop consuming expert capacity.
+                if not self.sc.mask_dead_slots:
+                    occ[:] = 1.0
+                args = (jnp.asarray(toks), jnp.asarray(pos),
+                        jnp.asarray(occ))
             with tr.span("serve.decode", active=len(active), slots=n):
                 logits, self.kv.cache, telem = self._decode(
-                    self.params, jnp.asarray(toks), self.kv.cache,
-                    jnp.asarray(pos), jnp.asarray(occ))
+                    self.params, args[0], self.kv.cache, args[1], args[2])
                 if self._trace_sync:
                     logits = jax.block_until_ready(logits)
             with tr.span("serve.sample", rows=len(active)):
                 nxt = self._sample_rows(logits, rows)
             self._record_telemetry(telem, len(active))
-            self._c["decode_steps"].inc()
-            self._c["slot_steps_active"].inc(len(active))
-            self._c["slot_steps_total"].inc(n)
-            for slot, req in active:
-                # the fed token's KV was just written at pos[slot]
-                self.kv.lengths[slot] = int(pos[slot]) + 1
-                self._append_token(req, nxt[slot], slot)
+            with tr.span("serve.append", rows=len(active)):
+                self._c["decode_steps"].inc()
+                self._c["slot_steps_active"].inc(len(active))
+                self._c["slot_steps_total"].inc(n)
+                for slot, req in active:
+                    # the fed token's KV was just written at pos[slot]
+                    self.kv.lengths[slot] = int(pos[slot]) + 1
+                    self._append_token(req, nxt[slot], slot)
         if tr.enabled:
             tr.counter("serve.queue", depth=len(self.queue))
             tr.counter("serve.slots", active=len(active))
@@ -719,29 +760,31 @@ class ServeEngine:
     def _record_telemetry(self, telem, n_active: int) -> None:
         if telem is None:
             return
-        entry = {"step": self.step_count, "active": n_active}
-        # Aggregate instruments cover the whole run in bounded memory;
-        # the raw entry lands in the keep_last_n ring for inspection.
-        # MoE FFN counters and MoA head-group counters are independent
-        # families — a model may have either or both.
-        if "expert_load" in telem:
-            entry.update(expert_load=np.asarray(telem["expert_load"]),
-                         overflow=np.asarray(telem["overflow"]),
-                         n_moe=float(telem["n_moe"]))
-            self._c["overflow_total"].inc(float(entry["overflow"].sum()))
-            self._h_overflow.observe(float(entry["overflow"].sum()))
-            for e, load in enumerate(entry["expert_load"].tolist()):
-                self._c_expert_load.child(expert=e).inc(float(load))
-        if "moa_load" in telem:
-            entry.update(moa_load=np.asarray(telem["moa_load"]),
-                         moa_overflow=np.asarray(telem["moa_overflow"]),
-                         n_moa=float(telem["n_moa"]))
-            self._c_moa_overflow.inc(float(entry["moa_overflow"].sum()))
-            self._h_moa_overflow.observe(float(entry["moa_overflow"].sum()))
-            for e, load in enumerate(entry["moa_load"].tolist()):
-                self._c_moa_load.child(expert=e).inc(float(load))
-        self._h_active.observe(n_active)
-        self._telemetry.append(entry)
+        with self.tracer.span("serve.telemetry"):
+            entry = {"step": self.step_count, "active": n_active}
+            # Aggregate instruments cover the whole run in bounded memory;
+            # the raw entry lands in the keep_last_n ring for inspection.
+            # MoE FFN counters and MoA head-group counters are independent
+            # families — a model may have either or both.
+            if "expert_load" in telem:
+                entry.update(expert_load=np.asarray(telem["expert_load"]),
+                             overflow=np.asarray(telem["overflow"]),
+                             n_moe=float(telem["n_moe"]))
+                self._c["overflow_total"].inc(float(entry["overflow"].sum()))
+                self._h_overflow.observe(float(entry["overflow"].sum()))
+                for e, load in enumerate(entry["expert_load"].tolist()):
+                    self._c_expert_load.child(expert=e).inc(float(load))
+            if "moa_load" in telem:
+                entry.update(moa_load=np.asarray(telem["moa_load"]),
+                             moa_overflow=np.asarray(telem["moa_overflow"]),
+                             n_moa=float(telem["n_moa"]))
+                self._c_moa_overflow.inc(float(entry["moa_overflow"].sum()))
+                self._h_moa_overflow.observe(
+                    float(entry["moa_overflow"].sum()))
+                for e, load in enumerate(entry["moa_load"].tolist()):
+                    self._c_moa_load.child(expert=e).inc(float(load))
+            self._h_active.observe(n_active)
+            self._telemetry.append(entry)
 
     @property
     def telemetry(self) -> list:

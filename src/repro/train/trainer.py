@@ -18,11 +18,12 @@
   ``straggler_factor`` × running median are logged as straggler events
   (the launcher's watchdog restarts/re-meshes on repeated events);
 * optional crash injection for the fault-tolerance tests;
-* optional chrome-trace capture (``trace_path``): each step records a
-  ``train.step`` span (plus ``train.data``/``train.checkpoint`` around
-  input and save work) with the trainer's tracer installed as the
-  ambient one, so kernel-backend call-site spans from the first traced
-  step nest under it (docs/observability.md).
+* spans: ``train.step`` around each step (which waits for its loss),
+  ``train.data`` and ``train.checkpoint`` around input and save work,
+  with the trainer's tracer installed as the ambient one.  They are
+  profiler annotations, so under ``jax.profiler.trace`` they share the
+  device trace's clock; with ``trace_path`` set they are also kept as
+  a chrome trace (docs/observability.md).
 """
 from __future__ import annotations
 

@@ -307,6 +307,82 @@ def test_traced_run_bit_identical_with_loadable_trace(moe_setup, tmp_path):
     assert len(events) == len(eng.tracer.events) + 1   # + process_name
 
 
+# ---------------------------------------------------------------------------
+# profiler: the engine's spans and named programs in one jax.profiler trace
+# ---------------------------------------------------------------------------
+
+ENGINE_SPANS = {"serve.step", "serve.schedule", "serve.admit",
+                "serve.prefix_probe", "serve.inputs", "serve.prefill_chunk",
+                "serve.kv_insert", "serve.decode", "serve.sample",
+                "serve.sync", "serve.telemetry", "serve.append",
+                "serve.retire"}
+
+
+@pytest.fixture(scope="module")
+def profiled(moe_setup, tmp_path_factory):
+    """The staggered trace served without a profiler, then again under
+    ``jax.profiler`` (Python tracer off): (tokens off, tokens on, the
+    host plane's ``serve.*`` events, the ``hlo_module`` names)."""
+    import glob
+
+    from jax.profiler import ProfileData
+    cfg, params = moe_setup
+    trace = _staggered_trace(cfg.vocab_size)
+    toks_off, _ = _run_engine(params, cfg, trace)
+    d = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(d, profiler_options=opts)
+    try:
+        toks_on, _ = _run_engine(params, cfg, trace)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)
+    spans, modules = [], set()
+    for plane in ProfileData.from_file(path).planes:
+        for ln in plane.lines:
+            for ev in ln.events:
+                st = dict(ev.stats)
+                if plane.name.startswith("/host") and \
+                        ev.name.startswith("serve."):
+                    spans.append((ev.name, ev.start_ns,
+                                  ev.start_ns + ev.duration_ns, st))
+                if "hlo_module" in st:
+                    modules.add(st["hlo_module"])
+    return toks_off, toks_on, spans, modules
+
+
+def test_profiler_on_keeps_greedy_tokens(profiled):
+    toks_off, toks_on, _, _ = profiled
+    assert toks_on == toks_off                   # tracing is observation
+
+
+def test_profiler_trace_holds_engine_spans_inside_steps(profiled):
+    _, _, spans, _ = profiled
+    assert ENGINE_SPANS <= {n for n, *_ in spans}
+    steps = [(s, e) for n, s, e, _ in spans if n == "serve.step"]
+    for name, s, e, _ in spans:
+        if name != "serve.step":
+            assert any(a <= s and e <= b for a, b in steps), name
+    stats = {}
+    for name, _, _, st in spans:
+        stats.setdefault(name, []).append(st)
+    assert sorted(st["step"] for st in stats["serve.step"]) == list(
+        range(len(steps)))
+    for st in stats["serve.admit"]:
+        assert {"rid", "slot", "wait_ms"} <= set(st) and st["wait_ms"] >= 0
+    assert sorted(st["rid"] for st in stats["serve.admit"]) == list(range(6))
+    for st in stats["serve.prefill_chunk"]:
+        assert st["tokens"] == st["Gp"] * st["C"]
+    assert all(st["slots"] == 4 for st in stats["serve.decode"])
+
+
+def test_profiler_trace_names_engine_programs(profiled):
+    _, _, _, modules = profiled
+    assert {"jit_decode_step", "jit_prefill_chunk",
+            "jit_sample_argmax"} <= modules
+
+
 def test_telemetry_ring_bounded_while_aggregates_count(moe_setup):
     cfg, params = moe_setup
     rs = np.random.RandomState(9)
