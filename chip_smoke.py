@@ -392,6 +392,7 @@ def main() -> int:
     log(f"compile cache: {enable_compile_cache()}")
     from repro.configs.base import get_config
     from repro.kernels import backend as backend_lib
+    from repro.kernels import gmm as gmm_lib
 
     base = get_config(ARCH)
     log(f"model {ARCH}: d_model={base.d_model} heads={base.n_heads}/"
@@ -417,6 +418,8 @@ def main() -> int:
         _free_workdir()
     fallbacks = backend_lib.fallbacks()
     log(f"kernel fallbacks: {sum(fallbacks.values())} {fallbacks}")
+    log(f"GMM plans by how their tiles were resolved: "
+        f"{gmm_lib.plan_sources()}")
     if fallbacks:
         log("FAILED: a kernel call fell back off the pallas kernels")
         return 1

@@ -107,8 +107,8 @@ class ModelConfig:
     # Force a fused dispatch/combine slab size; None auto-selects against
     # the VMEM budget.
     dispatch_e_block: int | None = None
-    # Consult the measured GMM tiling table (make tune-kernels); False
-    # pins the static 128-tile defaults.
+    # Size GMM tiles by the tiling table, else the tile rule
+    # (docs/kernels.md §Tiling autotune); False pins static 128 tiles.
     gmm_autotune: bool = True
     # Serve-time fused decode step (docs/kernels.md §Fused decode step):
     # decode-shaped MoE/MoA calls run routing + dispatch + expert FFN +

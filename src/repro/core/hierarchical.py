@@ -70,7 +70,7 @@ class HMoEArgs:
     kernel_backend: str | None = None
     dispatch_vmem_limit: int | None = None
     dispatch_e_block: int | None = None    # fused-kernel slab size; None=auto
-    gmm_autotune: bool = True              # measured GMM tilings (kernels.md)
+    gmm_autotune: bool = True              # GMM tile rule (kernels.md)
     dtype: Any = jnp.bfloat16
 
     @property

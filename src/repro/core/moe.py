@@ -89,9 +89,8 @@ class MoEArgs:
     # fits, else the largest fitting power-of-two slab); an explicit int
     # forces that slab size for both forward and backward.
     dispatch_e_block: int | None = None
-    # Consult the measured GMM tiling table (docs/kernels.md §Tiling
-    # autotune, seeded by `make tune-kernels`) when planning expert-FFN
-    # blocks; False pins the static 128-tile defaults.
+    # Plan expert-FFN GMM tiles by the tiling table, else the tile rule
+    # (docs/kernels.md §Tiling autotune); False pins static 128 tiles.
     gmm_autotune: bool = True
     # Serve-time fused decode: run routing + dispatch + expert FFN +
     # combine as ONE kernel launch (docs/kernels.md §Fused decode step).

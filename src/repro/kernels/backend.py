@@ -125,7 +125,8 @@ def block_plan(a, capacity: int, ctx: "ctx_lib.MeshContext | None" = None,
         ctx, (a.n_experts, capacity, a.d_model),
         ("experts", "expert_capacity", "embed"))
     (f,) = shard_shape(ctx, (a.d_ff,), ("expert_mlp",))
-    return gmm_lib.plan_blocks(e, c, d, f, dtype or a.dtype)
+    return gmm_lib.plan_blocks(e, c, d, f, dtype or a.dtype,
+                               autotune=getattr(a, "gmm_autotune", True))
 
 
 def _check_local_buffer(x, a, ctx, backend_name: str):
@@ -393,16 +394,11 @@ def _register_pallas() -> None:
         # Tile choice: leave bm/bn/bk unset so each GMM plans its own
         # per-shard operand shapes (the operands here ARE the per-shard
         # view — a shard_map body hands local blocks, validated above) —
-        # consulting the measured tuning table first, static defaults
-        # otherwise.  `MoEArgs.gmm_autotune=False` pins the defaults.
-        tiles = {}
-        if not getattr(a, "gmm_autotune", True):
-            from repro.kernels import gmm as gmm_lib
-            tiles = dict(bm=gmm_lib.DEFAULT_TILE, bn=gmm_lib.DEFAULT_TILE,
-                         bk=gmm_lib.DEFAULT_TILE)
+        # the tuning table first, the tile rule otherwise.
+        # `MoEArgs.gmm_autotune=False` pins the static defaults.
         with jax.named_scope("kernel.gmm"):
             return ops.expert_ffn(params, x, activation=a.activation,
-                                  **tiles)
+                                  autotune=getattr(a, "gmm_autotune", True))
 
     def _pallas_dispatch(x, p, a, *, ctx=None):
         p = _as_plan(p)
@@ -525,14 +521,9 @@ def _register_pallas() -> None:
                 vmem_limit=getattr(a, "dispatch_vmem_limit", None))
 
     def _pallas_gmm(x, w, a, *, ctx=None):
-        tiles = {}
-        if not getattr(a, "gmm_autotune", True):
-            from repro.kernels import gmm as gmm_lib
-            tiles = dict(bm=gmm_lib.DEFAULT_TILE, bn=gmm_lib.DEFAULT_TILE,
-                         bk=gmm_lib.DEFAULT_TILE)
         with jax.named_scope("kernel.gmm"):
             return ops.gmm(x, w.astype(x.dtype), activation="none",
-                           **tiles)
+                           autotune=getattr(a, "gmm_autotune", True))
 
     register(KernelBackend(name="pallas", expert_ffn=_pallas_expert_ffn,
                            dispatch=_pallas_dispatch,

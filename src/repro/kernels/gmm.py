@@ -6,15 +6,15 @@ FLOPs in the paper's models, and "we can increase computational efficiency
 simply by using a larger hidden layer").  On GPU the reference batches
 per-expert GEMMs; the TPU-native shape is one kernel whose grid walks
 (expert, row-block, col-block, k-block) with an f32 VMEM accumulator,
-MXU-aligned 128x128 tiles, and the activation fused into the final k-step
+MXU-aligned tiles, and the activation fused into the final k-step
 epilogue so the [E, C, d_ff] hidden never round-trips HBM at f32.
 
 Grid iteration order is (e, m, n, k) with k innermost: the accumulator tile
 stays VMEM-resident across the k loop (revolving output), and the x
 row-block is reused across all n — the standard TPU blocked-matmul
-schedule.  VMEM working set per step (bm=bn=bk=128): x tile 32 KiB +
-w tile 32 KiB + f32 acc 64 KiB ~= 128 KiB, far under the ~16 MiB budget;
-larger bn/bk amortize grid overhead until the d_ff dimension is consumed.
+schedule.  Each grid step costs ~0.35 us of fixed overhead on a v5e
+whatever its work, so a static 128^3 walk leaves a large GMM
+grid-step-bound: at 8 x 8192 x 7168 x 4864 bf16 it is 1.09M steps.
 
 Non-tile-aligned shapes are zero-padded up to the block plan (see
 :func:`plan_blocks`) and the output trimmed — zero rows/columns are inert
@@ -33,19 +33,29 @@ rematerialized with one extra no-activation GMM (the Appendix-D
 "recompute expert activations on the backward pass" policy) rather than
 saved, keeping forward residuals at (x, w).
 
-Tile sizes come from a **measured tuning table** when the caller leaves
-them unset: ``plan_blocks`` consults ``gmm_tunings.json`` (seeded by
-``make tune-kernels``, exact (E, C, K, N, dtype) keys) before its static
-128 defaults — on this interpret-mode host per-grid-step overhead
-dominates, so fewer/bigger blocks win by integer factors (the
-``kernel_backend_gmm_pallas`` gap in BENCH_micro.json).  Explicit
-``bm/bn/bk`` arguments always override the table.
+Tile sizes, when the caller leaves them unset, come from a **tile rule**
+(:func:`rule_tiles`): among tiles whose edges divide the dims (bn, bk:
+multiples of 128; bm: of the dtype's sublane) or are power-of-two
+multiples of those units, keep the one of least modelled time —
+max(padded FLOPs / MXU peak, HBM bytes with x re-read per n-block and w
+per m-block / HBM bandwidth) + ~0.35 us a grid step + the copies any
+padding forces — whose VMEM footprint (:func:`vmem_bytes`) fits the
+budget handed to Mosaic.  It is a pure function of (E, C, K, N, dtype,
+budget), so every caller gets it: serve prefill and decode, each training
+forward and backward matmul, MoA's projections, the per-shard shapes of
+the expert-parallel path.  Precedence in :func:`plan_blocks`: explicit
+``bm/bn/bk``; ``autotune=False`` (``MoEArgs.gmm_autotune``) pins
+``DEFAULT_TILE``; an exact-shape entry of ``gmm_tunings.json`` (an
+override; its committed entries are float32 shapes tuned in interpret mode
+on a CPU, which no chip path reaches); else the rule.
+:func:`plan_sources` counts, at trace time, how each plan was resolved.
 
 ``interpret`` resolves at call time through ``platform.interpret_mode``:
 compiled on a TPU, the Pallas interpreter elsewhere.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import json
 import os
@@ -69,9 +79,10 @@ def _sublane(dtype) -> int:
     return 16 if dtype == jnp.bfloat16 else 8
 
 
-# --- measured tiling table (docs/kernels.md §Tiling autotune) --------------
+# --- tiling table (docs/kernels.md §Tiling autotune) ------------------------
 
-# Static fallback tile edge when a shape has no measured entry.
+# The tile edge pinned by ``autotune=False`` and given to any explicit tile
+# argument left unset.
 DEFAULT_TILE = 128
 
 # Env var overriding the committed table path (tests point it at tmp
@@ -101,8 +112,8 @@ def load_tunings(path: str | None = None) -> dict:
 
     When ``REPRO_GMM_TUNINGS`` supplies the path, the override is
     *validated*: a missing or unparseable file raises
-    ``KernelBackendError`` instead of silently falling back to the static
-    defaults (an empty value keeps the documented "unset" meaning — the
+    ``KernelBackendError`` instead of silently falling back to the tile
+    rule (an empty value keeps the documented "unset" meaning — the
     committed table).
     """
     global _tunings_cache
@@ -147,14 +158,15 @@ def invalidate_tunings() -> None:
 
 def lookup_tiling(e: int, c: int, k: int, n: int,
                   dtype) -> tuple[int, int, int] | None:
-    """Measured (bm, bn, bk) for an exact shape, or None (use defaults)."""
+    """Table (bm, bn, bk) for an exact shape, or None (use the rule)."""
     return load_tunings().get(tuning_key(e, c, k, n, dtype))
 
 
 class BlockPlan(NamedTuple):
     """A per-shard block spec for one grouped matmul: padded operand shapes
     plus the (bm, bn, bk) tile walk.  ``padded == shape`` iff the local
-    dims were already tile-aligned."""
+    dims were already tile-aligned.  ``source`` says how the tiles were
+    resolved: ``explicit``, ``table``, ``rule`` or ``pinned``."""
     e: int
     c: int          # padded row dim (capacity)
     k: int          # padded contraction dim
@@ -162,6 +174,7 @@ class BlockPlan(NamedTuple):
     bm: int
     bn: int
     bk: int
+    source: str
 
     @property
     def grid(self) -> tuple[int, int, int, int]:
@@ -169,22 +182,124 @@ class BlockPlan(NamedTuple):
                 self.k // self.bk)
 
 
+# --- the tile rule: tiles sized from the shape and the VMEM budget ---------
+
+# Fixed cost of one grid step on the chip the kernels target: ~0.35 us,
+# measured on a v5e (a 128^3 walk runs at 0.27-0.37 us a step whatever the
+# work in it).  The MXU peak and HBM bandwidth are the chip's, from
+# ``platform``.
+_STEP_S = 0.35e-6
+
+
+def vmem_bytes(bm: int, bn: int, bk: int, dtype) -> int:
+    """Scoped VMEM one grid step holds: double-buffered x, w and out tiles,
+    the f32 accumulator, and what Mosaic adds in the kernel body — a copy
+    of the x tile fed to the MXU, an f32 tile for the epilogue's
+    activation, and under one f32 vreg row (128 lanes) a tile row of
+    scratch.  Checked against the least scoped-VMEM limit the v5e
+    compiler accepts for bf16 tiles from 256^3 to 1024^3: never under
+    it, and within 0.5 MiB of it where the epilogue is an activation."""
+    size = jnp.dtype(dtype).itemsize
+    return (2 * (bm * bk + bk * bn + bm * bn) + bm * bk) * size \
+        + (2 * bn + 128) * bm * 4
+
+
+def tile_edges(dim: int, unit: int) -> list[int]:
+    """Candidate tile edges for one dim: the multiples of ``unit`` that
+    divide the dim rounded up to ``unit`` (no padding past that rounding),
+    and the power-of-two multiples of ``unit`` below it (these pad further;
+    the cost model charges their copy)."""
+    full = round_up(dim, unit)
+    q = full // unit
+    divisors = {unit * d for d in range(1, q + 1) if q % d == 0}
+    powers = {unit << j for j in range(q.bit_length()) if unit << j <= full}
+    return sorted(divisors | powers)
+
+
+def plan_seconds(e: int, c: int, k: int, n: int, bm: int, bn: int, bk: int,
+                 dtype) -> float:
+    """Modelled time of one GMM call walking (bm, bn, bk) tiles: the larger
+    of the padded FLOPs at peak and the HBM bytes at peak bandwidth (x read
+    once per n-block, w once per m-block, out written once), plus the
+    per-step cost, plus the copies that padding and trimming make."""
+    size = jnp.dtype(dtype).itemsize
+    cp, kp, np_ = round_up(c, bm), round_up(k, bk), round_up(n, bn)
+    m_blocks, n_blocks = cp // bm, np_ // bn
+    steps = e * m_blocks * n_blocks * (kp // bk)
+    flops = 2 * e * cp * kp * np_
+    moved = e * size * (cp * kp * n_blocks + kp * np_ * m_blocks + cp * np_)
+    copied = 0
+    for (a, b), (pa, pb) in (((c, k), (cp, kp)), ((k, n), (kp, np_)),
+                             ((c, n), (cp, np_))):
+        if (a, b) != (pa, pb):
+            copied += e * size * (a * b + pa * pb)
+    return (max(flops / platform.PEAK_BF16_FLOPS,
+                moved / platform.HBM_BYTES_PER_S)
+            + _STEP_S * steps + copied / platform.HBM_BYTES_PER_S)
+
+
+@functools.lru_cache(maxsize=1024)
+def rule_tiles(e: int, c: int, k: int, n: int, dtype_name: str,
+               vmem_limit: int) -> tuple[int, int, int]:
+    """The (bm, bn, bk) of least :func:`plan_seconds` whose
+    :func:`vmem_bytes` fits ``vmem_limit``; ties go to fewer grid steps,
+    then to the smaller tiles.  Pure in its arguments."""
+    dtype = jnp.dtype(dtype_name)
+    best = None
+    for bm in tile_edges(c, _sublane(dtype)):
+        for bn in tile_edges(n, 128):
+            for bk in tile_edges(k, 128):
+                if vmem_bytes(bm, bn, bk, dtype) > vmem_limit:
+                    continue
+                steps = (-(-c // bm)) * (-(-n // bn)) * (-(-k // bk))
+                key = (plan_seconds(e, c, k, n, bm, bn, bk, dtype), steps,
+                       bm, bn, bk)
+                if best is None or key < best:
+                    best = key
+    if best is None:
+        raise ValueError(f"no GMM tile of {e}x{c}x{k}x{n}x{dtype_name} "
+                         f"fits {vmem_limit} bytes of VMEM")
+    return best[2:]
+
+
+# How each GMM plan traced in this process was resolved.
+_PLAN_SOURCES: collections.Counter = collections.Counter()
+
+
+def plan_sources() -> dict[str, int]:
+    """GMM plans traced in this process so far, by how their tiles were
+    resolved (``explicit`` / ``table`` / ``rule`` / ``pinned``).  Counted
+    once per traced kernel call, as backend.fallbacks() is."""
+    return dict(_PLAN_SOURCES)
+
+
 def plan_blocks(e: int, c: int, k: int, n: int, dtype=jnp.float32, *,
                 bm: int | None = None, bn: int | None = None,
-                bk: int | None = None) -> BlockPlan:
+                bk: int | None = None, autotune: bool = True) -> BlockPlan:
     """Derive the block plan for a (possibly non-tile-aligned) local shape.
 
-    Tile sizes left as ``None`` consult the measured tuning table first
-    (:func:`lookup_tiling`, exact-shape keys) and fall back to
-    ``DEFAULT_TILE``; explicit values always win.  Blocks are clamped to
-    the (tile-rounded) dims so small problems don't pad all the way to
-    128, and dims are padded up to a whole number of blocks instead of
-    asserting divisibility.
+    Precedence: explicit ``bm/bn/bk`` (any left ``None`` take
+    ``DEFAULT_TILE``); else, with ``autotune`` off, ``DEFAULT_TILE`` for
+    all three ("pinned"); else the tuning table's exact-shape entry
+    (:func:`lookup_tiling`); else the rule (:func:`rule_tiles`), sized
+    against ``platform.DEFAULT_VMEM_LIMIT``, the limit the kernel hands
+    Mosaic.  Blocks are clamped to the (tile-rounded) dims so small
+    problems don't pad all the way to 128, and dims are padded up to a
+    whole number of blocks instead of asserting divisibility.
     """
-    if bm is None and bn is None and bk is None:
+    if bm is not None or bn is not None or bk is not None:
+        source = "explicit"
+    elif not autotune:
+        source = "pinned"
+    else:
         tuned = lookup_tiling(e, c, k, n, dtype)
         if tuned is not None:
+            source = "table"
             bm, bn, bk = tuned
+        else:
+            source = "rule"
+            bm, bn, bk = rule_tiles(e, c, k, n, jnp.dtype(dtype).name,
+                                    platform.DEFAULT_VMEM_LIMIT)
     bm = DEFAULT_TILE if bm is None else bm
     bn = DEFAULT_TILE if bn is None else bn
     bk = DEFAULT_TILE if bk is None else bk
@@ -193,7 +308,7 @@ def plan_blocks(e: int, c: int, k: int, n: int, dtype=jnp.float32, *,
     bn = min(bn, round_up(n, 128))
     bk = min(bk, round_up(k, 128))
     return BlockPlan(e=e, c=round_up(c, bm), k=round_up(k, bk),
-                     n=round_up(n, bn), bm=bm, bn=bn, bk=bk)
+                     n=round_up(n, bn), bm=bm, bn=bn, bk=bk, source=source)
 
 
 def _pad3(x: jax.Array, d1: int, d2: int) -> jax.Array:
@@ -246,11 +361,14 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int, activation: str):
 
 
 def _gmm_raw(x: jax.Array, w: jax.Array, activation: str,
-             bm: int, bn: int, bk: int, interpret: bool) -> jax.Array:
+             bm: int | None, bn: int | None, bk: int | None, autotune: bool,
+             interpret: bool) -> jax.Array:
     """Pad -> pallas_call -> trim.  No autodiff rule (see ``gmm``)."""
     e, c, k = x.shape
     _, _, n = w.shape
-    bp = plan_blocks(e, c, k, n, x.dtype, bm=bm, bn=bn, bk=bk)
+    bp = plan_blocks(e, c, k, n, x.dtype, bm=bm, bn=bn, bk=bk,
+                     autotune=autotune)
+    _PLAN_SOURCES[bp.source] += 1
     xp = _pad3(x, bp.c, bp.k)
     wp = _pad3(w, bp.k, bp.n)
     n_k = bp.k // bp.bk
@@ -274,25 +392,27 @@ def _gmm_raw(x: jax.Array, w: jax.Array, activation: str,
     return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
-def _gmm(x, w, activation, bm, bn, bk, interpret):
-    return _gmm_raw(x, w, activation, bm, bn, bk, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
+def _gmm(x, w, activation, bm, bn, bk, autotune, interpret):
+    return _gmm_raw(x, w, activation, bm, bn, bk, autotune, interpret)
 
 
-def _gmm_fwd(x, w, activation, bm, bn, bk, interpret):
-    return _gmm_raw(x, w, activation, bm, bn, bk, interpret), (x, w)
+def _gmm_fwd(x, w, activation, bm, bn, bk, autotune, interpret):
+    return (_gmm_raw(x, w, activation, bm, bn, bk, autotune, interpret),
+            (x, w))
 
 
-def _gmm_bwd(activation, bm, bn, bk, interpret, res, g):
+def _gmm_bwd(activation, bm, bn, bk, autotune, interpret, res, g):
     x, w = res
+    tiles = (bm, bn, bk, autotune, interpret)
     if activation != "none":
         # Rematerialize the pre-activation z (one extra GMM) and fold the
         # activation derivative into the incoming cotangent.
-        z = _gmm_raw(x, w, "none", bm, bn, bk, interpret)
+        z = _gmm_raw(x, w, "none", *tiles)
         g = (g.astype(jnp.float32)
              * _act_grad(z.astype(jnp.float32), activation)).astype(g.dtype)
-    dx = _gmm_raw(g, jnp.swapaxes(w, 1, 2), "none", bm, bn, bk, interpret)
-    dw = _gmm_raw(jnp.swapaxes(x, 1, 2), g, "none", bm, bn, bk, interpret)
+    dx = _gmm_raw(g, jnp.swapaxes(w, 1, 2), "none", *tiles)
+    dw = _gmm_raw(jnp.swapaxes(x, 1, 2), g, "none", *tiles)
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 
@@ -300,17 +420,17 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("activation", "bm", "bn", "bk",
-                                             "interpret"))
+                                             "autotune", "interpret"))
 def gmm(x: jax.Array, w: jax.Array, *, activation: str = "none",
         bm: int | None = None, bn: int | None = None, bk: int | None = None,
-        interpret: bool | None = None) -> jax.Array:
+        autotune: bool = True, interpret: bool | None = None) -> jax.Array:
     """[E, C, K] x [E, K, N] -> [E, C, N] with optional fused activation.
 
     Differentiable (custom VJP); non-tile-aligned C/K/N are zero-padded to
     the :func:`plan_blocks` boundaries and the output trimmed.  Tile sizes
-    left as ``None`` use the measured tuning table / static defaults via
-    :func:`plan_blocks` — each backward-pass GMM re-plans for its own
-    operand shapes, so grad matmuls get their own tuned tiles.
+    left as ``None`` come from the tuning table, else from the tile rule
+    (``autotune=False``: ``DEFAULT_TILE``) via :func:`plan_blocks` — each
+    backward-pass GMM re-plans for its own operand shapes.
     """
-    return _gmm(x, w, activation, bm, bn, bk,
+    return _gmm(x, w, activation, bm, bn, bk, autotune,
                 platform.interpret_mode(interpret))

@@ -23,23 +23,26 @@ from repro.kernels import gmm as gmm_lib
 from repro.kernels import topk_gating as topk_lib
 
 
-def gmm(x, w, *, activation: str = "none", bm=None, bn=None, bk=None):
-    return gmm_lib.gmm(x, w, activation=activation, bm=bm, bn=bn, bk=bk)
+def gmm(x, w, *, activation: str = "none", bm=None, bn=None, bk=None,
+        autotune: bool = True):
+    return gmm_lib.gmm(x, w, activation=activation, bm=bm, bn=bn, bk=bk,
+                       autotune=autotune)
 
 
 def expert_ffn(params, x, *, activation: str = "relu",
-               bm=None, bn=None, bk=None):
+               bm=None, bn=None, bk=None, autotune: bool = True):
     """Two fused GMMs: up-projection (+act) then down-projection.
 
     x: [E, C, d]; params carries w1 [E,d,f], w2 [E,f,d], (w3 for swiglu).
     Differentiable end-to-end via the GMM custom VJP.  ``bm/bn/bk`` cap
     the tile walk; left as ``None`` each GMM plans its own operand shapes
-    (measured tuning table, then static defaults — see gmm.plan_blocks).
+    (tuning table, then the tile rule; ``autotune=False`` pins
+    ``DEFAULT_TILE`` — see gmm.plan_blocks).
     """
     dt = x.dtype
     w1 = params["w1"].astype(dt)
     w2 = params["w2"].astype(dt)
-    blocks = dict(bm=bm, bn=bn, bk=bk)
+    blocks = dict(bm=bm, bn=bn, bk=bk, autotune=autotune)
     if activation == "swiglu":
         h = gmm(x, w1, activation="silu", **blocks)
         g = gmm(x, params["w3"].astype(dt), activation="none", **blocks)

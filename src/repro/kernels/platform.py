@@ -13,7 +13,9 @@ like a slow one.
 
 The VMEM budget lives here too: the kernels size their blocks against it
 and hand the same number to Mosaic as the kernel's scoped-VMEM limit, so
-the estimate and the compiler's limit cannot drift apart.
+the estimate and the compiler's limit cannot drift apart.  So do the
+chip's peaks, which the GMM's tile rule models its cost with and the
+roofline analysis (``launch/mesh.CHIP``) reads.
 """
 from __future__ import annotations
 
@@ -24,6 +26,11 @@ from jax.experimental.pallas import tpu as pltpu
 # chip has 128 MiB of VMEM in all).  Passed to Mosaic as
 # ``vmem_limit_bytes`` by every pallas_call in this package.
 DEFAULT_VMEM_LIMIT = 16 * 1024 * 1024
+
+# The chip the kernels target (TPU v5e): bf16 MXU peak and HBM bandwidth,
+# per chip.
+PEAK_BF16_FLOPS = 197e12
+HBM_BYTES_PER_S = 819e9
 
 
 def interpret_mode(interpret: bool | None = None) -> bool:

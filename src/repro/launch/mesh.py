@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels import platform
 from repro.sharding import context as ctx_lib
 
 
@@ -37,7 +38,7 @@ def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
 # TPU v5e hardware constants used by the roofline analysis.
 CHIP = {
     "name": "tpu-v5e",
-    "peak_bf16_flops": 197e12,      # per chip
-    "hbm_bandwidth": 819e9,         # bytes/s per chip
+    "peak_bf16_flops": platform.PEAK_BF16_FLOPS,    # per chip
+    "hbm_bandwidth": platform.HBM_BYTES_PER_S,      # bytes/s per chip
     "ici_link_bandwidth": 50e9,     # bytes/s per link
 }

@@ -78,8 +78,8 @@ def main():
                     help="force the fused dispatch/combine expert-slab "
                          "size; default: auto-select against the budget")
     ap.add_argument("--no-gmm-autotune", action="store_true",
-                    help="ignore the measured GMM tiling table "
-                         "(make tune-kernels) and pin static 128 tiles")
+                    help="ignore the GMM tiling table and the tile "
+                         "rule and pin static 128 tiles")
     ap.add_argument("--router-policy", default=None,
                     help="routing policy override (docs/routing.md): "
                          "noisy_topk | batchwise | threshold | "

@@ -244,44 +244,153 @@ def test_tuning_table_lookup_and_precedence(tmp_path, monkeypatch):
     monkeypatch.setenv(gmm_lib.TUNINGS_ENV, str(path))
     # tuned entry wins when tiles are unset
     bp = gmm_lib.plan_blocks(4, 256, 64, 96, jnp.float32)
-    assert (bp.bm, bp.bn, bp.bk) == (256, 128, 128)
+    assert (bp.bm, bp.bn, bp.bk, bp.source) == (256, 128, 128, "table")
     # explicit arguments beat the table
     bp = gmm_lib.plan_blocks(4, 256, 64, 96, jnp.float32, bm=128, bn=128,
                              bk=128)
     assert bp.bm == 128
-    # unknown shape -> static defaults
+    assert bp.source == "explicit"
+    # unknown shape -> the rule's tiles
     bp = gmm_lib.plan_blocks(4, 256, 128, 96, jnp.float32)
-    assert (bp.bm, bp.bn, bp.bk) == (128, 128, 128)
+    assert (bp.bm, bp.bn, bp.bk) == gmm_lib.rule_tiles(
+        4, 256, 128, 96, "float32", gmm_lib.platform.DEFAULT_VMEM_LIMIT)
+    assert bp.source == "rule"
+    # autotune off pins the static tiles, table or not
+    for shape in ((4, 256, 64, 96), (4, 256, 128, 96)):
+        bp = gmm_lib.plan_blocks(*shape, jnp.float32, autotune=False)
+        assert (bp.bm, bp.bn, bp.bk, bp.source) == (128, 128, 128, "pinned")
     # metadata keys are not tilings
     assert "_meta" not in gmm_lib.load_tunings(str(path))
 
 
-def test_gmm_tuned_tiles_match_default(tmp_path, monkeypatch):
-    """A tuned entry changes the tile walk, never the numbers: fwd + grad
-    parity between table-resolved and static-default tiles.  (Unique
-    operand dims so the None-tile jit cache can't have been primed with a
-    different table.)"""
-    e, c, k, n = 5, 136, 72, 80
+# tol: the forward's; grads get 10x.  f32 partial sums over K differ only
+# in order (~K * eps * |y|: 1e-5 at K = 72, 1e-4 at K = 300, |y| <~ 60);
+# a bf16 output may land one ulp (2^-8) apart.
+@pytest.mark.parametrize("source,e,c,k,n,dtype,tol", [
+    ("table", 5, 136, 72, 80, jnp.float32, 1e-5),
+    ("rule", 3, 200, 300, 260, jnp.float32, 1e-4),   # ragged on every dim
+    ("rule", 2, 40, 520, 1000, jnp.bfloat16, 1e-2),
+])
+def test_gmm_tuned_tiles_match_default(tmp_path, monkeypatch, source, e, c,
+                                       k, n, dtype, tol):
+    """Resolved tiles (a table entry, or the rule's on ragged shapes)
+    change the tile walk, never the numbers: fwd + grad parity against
+    static 128^3 tiles.  (Unique operand dims so the None-tile jit cache
+    can't have been primed with a different table.)"""
+    table = ({gmm_lib.tuning_key(e, c, k, n, dtype): [136, 128, 128]}
+             if source == "table" else {})
     path = tmp_path / "tunings.json"
-    path.write_text(json.dumps(
-        {gmm_lib.tuning_key(e, c, k, n, jnp.float32): [136, 128, 128]}))
+    path.write_text(json.dumps(table))
     monkeypatch.setenv(gmm_lib.TUNINGS_ENV, str(path))
+    bp = gmm_lib.plan_blocks(e, c, k, n, dtype)
+    assert bp.source == source and (bp.bm, bp.bn, bp.bk) != (128, 128, 128)
     rng = np.random.default_rng(9)
-    x = jnp.asarray(rng.normal(size=(e, c, k)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(e, k, n)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(e, c, k)), dtype)
+    w = jnp.asarray(rng.normal(size=(e, k, n)), dtype)
 
     def loss(x_, w_, **tiles):
-        return jnp.sum(ops.gmm(x_, w_, activation="relu", **tiles) ** 2)
+        y = ops.gmm(x_, w_, activation="relu", **tiles)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
 
-    y_tuned = ops.gmm(x, w, activation="relu")            # table-resolved
+    y_tuned = ops.gmm(x, w, activation="relu")            # resolved
     y_def = ops.gmm(x, w, activation="relu", bm=128, bn=128, bk=128)
-    np.testing.assert_allclose(np.asarray(y_tuned), np.asarray(y_def),
-                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(y_tuned, np.float32),
+                               np.asarray(y_def, np.float32),
+                               rtol=tol, atol=tol)
     gt = jax.grad(loss, argnums=(0, 1))(x, w)
     gd = jax.grad(loss, argnums=(0, 1))(x, w, bm=128, bn=128, bk=128)
     for a_, b_ in zip(gt, gd):
-        np.testing.assert_allclose(np.asarray(a_), np.asarray(b_),
-                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(a_, np.float32),
+                                   np.asarray(b_, np.float32),
+                                   rtol=10 * tol, atol=10 * tol)
+
+
+# The expert GMMs of the benchmark cells, bf16: kimi-k2 widths (d 7168,
+# experts 2048 wide) at a 512-token prefill chunk (C = 512) and a 32-slot
+# decode step (C = 32), 16 experts; arctic widths (d 7168, experts 4864
+# wide) in a 2 x 4096-token train step, 8 experts at C = T, with the dw
+# shapes of its backward pass.
+BENCH_GMM_SHAPES = {
+    "chat-prefill-up": (16, 512, 7168, 2048),
+    "chat-prefill-down": (16, 512, 2048, 7168),
+    "chat-decode-up": (16, 32, 7168, 2048),
+    "chat-decode-down": (16, 32, 2048, 7168),
+    "train-up": (8, 8192, 7168, 4864),
+    "train-down": (8, 8192, 4864, 7168),
+    "train-dw-up": (8, 7168, 8192, 4864),
+    "train-dw-down": (8, 4864, 8192, 7168),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BENCH_GMM_SHAPES))
+def test_rule_tiles_bench_shapes(case):
+    """The rule tiles the benchmark's bf16 shapes without padding, inside
+    the VMEM budget by the kernel's own estimate, and in far fewer grid
+    steps than the 128^3 walk (>= 100x for the train shapes)."""
+    e, c, k, n = BENCH_GMM_SHAPES[case]
+    bp = gmm_lib.plan_blocks(e, c, k, n, jnp.bfloat16)
+    assert bp.source == "rule"
+    assert (bp.c, bp.k, bp.n) == (c, k, n)
+    assert gmm_lib.vmem_bytes(bp.bm, bp.bn, bp.bk, jnp.bfloat16) \
+        <= gmm_lib.platform.DEFAULT_VMEM_LIMIT
+    pinned = gmm_lib.plan_blocks(e, c, k, n, jnp.bfloat16, autotune=False)
+    ratio = np.prod(pinned.grid) / np.prod(bp.grid)
+    assert ratio >= (100 if case.startswith("train") else 10), ratio
+    assert gmm_lib.plan_seconds(e, c, k, n, bp.bm, bp.bn, bp.bk,
+                                jnp.bfloat16) \
+        < gmm_lib.plan_seconds(e, c, k, n, 128, 128, 128, jnp.bfloat16) / 10
+
+
+def test_rule_tiles_fit_budget_and_pay_for_padding():
+    """The rule is pure in (E, C, K, N, dtype, budget), fits the budget it
+    is given, and pads past the 128 / sublane rounding only where the
+    model says the copy pays."""
+    limit = gmm_lib.platform.DEFAULT_VMEM_LIMIT
+    for shape in ((8, 8192, 7168, 4864), (3, 200, 300, 260),
+                  (1, 4100, 4100, 4100)):
+        for budget in (limit, limit // 4):
+            tiles = gmm_lib.rule_tiles(*shape, "bfloat16", budget)
+            assert tiles == gmm_lib.rule_tiles(*shape, "bfloat16", budget)
+            assert gmm_lib.vmem_bytes(*tiles, jnp.bfloat16) <= budget
+    # 4100 rows round to 4112 = 16 * 257 (sublane) and 4100 columns to
+    # 4224 = 128 * 33: the only tiles that pad no further are 16 or 4112
+    # rows by 128 x {1, 3, 11, 33} columns.  The rule pads the rows past
+    # that rounding because the model says the copy pays, so its plan
+    # beats every tile that pads no further.
+    e, c, k, n = 1, 4100, 4100, 4100
+    bp = gmm_lib.plan_blocks(e, c, k, n, jnp.bfloat16)
+    assert bp.c > 4112
+    no_pad = min(
+        gmm_lib.plan_seconds(e, c, k, n, bm, bn, bk, jnp.bfloat16)
+        for bm in (16, 4112) for bn in (128, 384, 1408, 4224)
+        for bk in (128, 384, 1408, 4224)
+        if gmm_lib.vmem_bytes(bm, bn, bk, jnp.bfloat16) <= limit)
+    assert gmm_lib.plan_seconds(e, c, k, n, bp.bm, bp.bn, bp.bk,
+                                jnp.bfloat16) < no_pad
+    with pytest.raises(ValueError, match="fits"):
+        gmm_lib.rule_tiles(1, 64, 128, 128, "float32", 1024)
+
+
+def test_plan_sources_counts_each_path(tmp_path, monkeypatch):
+    """Each traced GMM counts how its plan was resolved."""
+    e, c, k, n = 2, 24, 40, 56
+    path = tmp_path / "tunings.json"
+    path.write_text(json.dumps(
+        {gmm_lib.tuning_key(e, c, k, n, jnp.float32): [24, 128, 128]}))
+    monkeypatch.setenv(gmm_lib.TUNINGS_ENV, str(path))
+    x = jnp.ones((e, c, k), jnp.float32)
+    w = jnp.ones((e, k, n), jnp.float32)
+    x2 = jnp.ones((e, c + 8, k), jnp.float32)      # no table entry
+    for source, call in (
+            ("explicit", lambda: ops.gmm(x, w, bm=8, bn=128, bk=128)),
+            ("table", lambda: ops.gmm(x, w)),
+            ("rule", lambda: ops.gmm(x2, w)),
+            ("pinned", lambda: ops.gmm(x2, w, autotune=False))):
+        before = gmm_lib.plan_sources()
+        call()
+        after = gmm_lib.plan_sources()
+        assert after.get(source, 0) == before.get(source, 0) + 1, source
+        assert sum(after.values()) == sum(before.values()) + 1
 
 
 def test_committed_tuning_table_is_valid():

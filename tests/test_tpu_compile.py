@@ -12,6 +12,7 @@ only one process may hold the TPU library, and under pytest-xdist every
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,13 +68,40 @@ def _kernel_in(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+# The expert GMMs of the benchmark cells, (E, C, K, N), tiled by the rule
+# (gmm.plan_blocks with nothing in the table): kimi-k2 widths at a
+# 512-token prefill chunk and a 32-slot decode step, 16 experts; arctic
+# widths (experts 4864 wide) in a 2 x 4096-token train step, 8 experts at
+# C = T.  The gradient runs the backward GMMs on their own rule plans.
+GMM_SHAPES = {
+    "chat-prefill-up": (16, 512, D, F),
+    "chat-prefill-down": (16, 512, F, D),
+    "chat-decode-up": (16, 32, D, F),
+    "chat-decode-down": (16, 32, F, D),
+    "train-up": (8, 8192, D, 4864),
+    "train-down": (8, 8192, 4864, D),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GMM_SHAPES))
 @pytest.mark.parametrize("activation", ["silu", "none"])
-def test_gmm_compiles(compile_for, activation):
-    c = compile_for(
-        lambda x, w: gmm_lib.gmm(x, w, activation=activation,
-                                 interpret=False),
-        ((16, 128, D), BF16), ((16, D, F), BF16))
-    assert _kernel_in(c)
+def test_gmm_compiles(compile_for, activation, case):
+    e, c, k, n = GMM_SHAPES[case]
+    assert gmm_lib.plan_blocks(e, c, k, n, BF16).source == "rule"
+
+    def fwd(x, w):
+        return gmm_lib.gmm(x, w, activation=activation, interpret=False)
+
+    def loss(x, w):
+        return jnp.sum(fwd(x, w).astype(jnp.float32))
+
+    specs = ((e, c, k), BF16), ((e, k, n), BF16)
+    # The benchmark's GMM readers match the kernel's instruction by its
+    # name less the instance number: `gmm` (bench/trace_reduce.Op.kind).
+    kernels = re.findall(r"%(\S+) = [^\n]*tpu_custom_call",
+                         compile_for(fwd, *specs).as_text())
+    assert kernels and all(re.fullmatch(r"gmm\.\d+", k_) for k_ in kernels)
+    assert _kernel_in(compile_for(jax.grad(loss, argnums=(0, 1)), *specs))
 
 
 # (T tokens, k, E, C): decode (8 slots) and prefill (128-token prompt) of
