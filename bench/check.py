@@ -1,6 +1,9 @@
 """How ``correct`` is decided: the timed path's output against the plain
 reference (``bench/reference.py``), each number beside its limit.
 
+The reference runs the layers of the configuration's architecture
+module (``arch``, ``bench/archs/<arch>.py``) on that module's weights.
+
 Serving.  For a sample of finished requests, the reference runs once over
 each prompt with its served tokens.  A served (greedy) token's gap is
 the amount by which its reference logit lies below the reference's best
@@ -44,7 +47,7 @@ def logit_gaps(lg: np.ndarray, tokens: np.ndarray) -> np.ndarray:
     return lg.max(-1) - lg[np.arange(len(tokens)), tokens]
 
 
-def serve_numbers(seqs, m: dict, seed: int, pad_to: int,
+def serve_numbers(arch, seqs, m: dict, seed: int, pad_to: int,
                   control: bool = False) -> dict:
     """Gaps of the served tokens under the reference: ``max_logit_gap``,
     the widest, and ``mean_logit_gap``, the mean over every served
@@ -54,13 +57,13 @@ def serve_numbers(seqs, m: dict, seed: int, pad_to: int,
     if not seqs:
         return {"max_logit_gap": float("inf"),
                 "mean_logit_gap": float("inf")}
-    params = weights.make(m, seed)
-    ref = reference.served_logits(params, seqs, m, pad_to=pad_to)
+    params = weights.make(arch, m, seed)
+    ref = reference.served_logits(arch, params, seqs, m, pad_to=pad_to)
     gaps = np.concatenate([logit_gaps(lg, t) for lg, (_, t) in zip(ref, seqs)])
     out = {"max_logit_gap": float(gaps.max()),
            "mean_logit_gap": float(gaps.mean())}
     if control:
-        low = reference.served_logits(params, seqs, m, control="fp8",
+        low = reference.served_logits(arch, params, seqs, m, control="fp8",
                                       pad_to=pad_to)
         cg = np.concatenate([logit_gaps(r, q.argmax(-1))
                              for r, q in zip(ref, low)])
@@ -76,7 +79,7 @@ def batches(m: dict, seed: int, n: int, data: dict) -> list:
             for step in range(n)]
 
 
-def train_readings(m: dict, seed: int, cell: dict, data: dict,
+def train_readings(arch, m: dict, seed: int, cell: dict, data: dict,
                    control: str | None = None, half: bool = False) -> dict:
     """The reference's losses, first-gradient norms and change norms over
     the cell's checked steps.  ``half`` is a planted fault: the reference
@@ -89,10 +92,10 @@ def train_readings(m: dict, seed: int, cell: dict, data: dict,
         bs = [{k: v[: max(1, v.shape[0] // 2)] for k, v in b.items()}
               for b in bs]
     bs = [{k: jnp.asarray(v) for k, v in b.items()} for b in bs]
-    params = weights.make(m, seed)
-    losses, g1, params = reference.train_steps(params, bs, m,
+    params = weights.make(arch, m, seed)
+    losses, g1, params = reference.train_steps(arch, params, bs, m,
                                                cell["optimizer"], control)
-    p0 = weights.make(m, seed)
+    p0 = weights.make(arch, m, seed)
     diff = jax.jit(lambda a, b: {k: jnp.sqrt(jnp.sum(jnp.square(
         a[k].astype(jnp.float32) - b[k].astype(jnp.float32)))) for k in a})
     change = {k: float(v) for k, v in diff(params, p0).items()}

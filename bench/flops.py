@@ -1,12 +1,12 @@
 """Operations and bytes, from the shapes of a configuration (``dims`` of
 ``bench/configs/<config>.json``), and the peaks of the device they ran on.
 
-Model FLOPs count the multiply-adds a token needs (2 per MAC): the
-attention projections, the scores and values over the context it
-attends, the router, the k experts it is routed to, the dense FFN, and
-the unembedding.  A training token costs three times its forward pass
-(forward, and backward for activations and weights); recomputation is
-not counted.  Bytes are bf16 (2 a number).
+Model FLOPs count the multiply-adds a token needs (2 per MAC); the
+configuration's architecture module counts one token's forward pass
+(``token_flops``), with this module's expert GMM count.  A training
+token costs three times its forward pass (forward, and backward for
+activations and weights); recomputation is not counted.  Bytes are bf16
+(2 a number).
 """
 from __future__ import annotations
 
@@ -27,22 +27,12 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def token_flops(m: dict, context: float) -> float:
-    """Forward FLOPs of one token that attends ``context`` positions."""
-    d, hd = m["d_model"], m["head_dim"]
-    h, kv = m["n_heads"], m["n_kv_heads"]
-    per_layer = (2 * d * hd * (2 * h + 2 * kv)       # q, k, v, o
-                 + 2 * 2 * h * hd * context           # scores and values
-                 + 2 * d * m["n_experts"]             # router
-                 + gmm_flops(m, m["k"])               # k experts
-                 + 2 * 3 * d * m["d_dense"])          # dense SwiGLU FFN
-    return m["n_layers"] * per_layer + 2 * d * m["vocab"]
-
-
-def train_flops(m: dict, seq_len: int, tokens: int) -> float:
+def train_flops(arch, m: dict, seq_len: int, tokens: int) -> float:
     """Forward and backward FLOPs of ``tokens`` tokens in sequences of
-    ``seq_len`` (causal: a token attends (seq_len + 1) / 2 on average)."""
-    return 3 * tokens * token_flops(m, (seq_len + 1) / 2)
+    ``seq_len`` (causal: a token attends (seq_len + 1) / 2 on average),
+    each token's forward pass counted by the architecture module
+    ``arch`` (``token_flops``)."""
+    return 3 * tokens * arch.token_flops(m, (seq_len + 1) / 2)
 
 
 def gmm_flops(m: dict, rows: float) -> float:

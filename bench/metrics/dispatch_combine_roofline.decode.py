@@ -12,7 +12,7 @@ def read(ctx):
     if not tr or progs is None or tr["decode_entries"] <= 0:
         return None
     scale = tr["decode_steps"] / tr["decode_entries"]
-    tokens = tr["slot_steps_active"] * m["n_layers"]
+    tokens = tr["slot_steps_active"] * ctx["arch"].moe_layers(m)
     nbytes = flops.dispatch_combine_bytes(m, tokens,
                                           tr["decode_kept"] * scale)
     least = nbytes / ctx["peak"]["hbm_bytes_per_s"]

@@ -13,8 +13,8 @@ def read(ctx):
         return None
     kept = tr["decode_kept"]
     # telemetry sums the layers: experts touched per step is the count of
-    # experts with a row in some layer, so read their weights per layer
-    experts = tr["decode_touched"] * m["n_layers"]
+    # experts with a row in some layer, so read their weights per MoE layer
+    experts = tr["decode_touched"] * ctx["arch"].moe_layers(m)
     least = flops.least_time(flops.gmm_flops(m, kept),
                              flops.gmm_bytes(m, kept, experts), ctx["peak"])
     least *= tr["decode_steps"] / tr["decode_entries"]

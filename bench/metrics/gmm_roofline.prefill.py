@@ -11,8 +11,9 @@ def read(ctx):
     progs = _kernels.decode_program(ctx["trace"])
     if not tr or progs is None or not progs[1] or tr["prefill_tokens"] <= 0:
         return None
-    rows = tr["prefill_tokens"] * m["k"] * m["n_layers"]
-    experts = tr["prefill_calls"] * m["n_experts"] * m["n_layers"]
+    layers = ctx["arch"].moe_layers(m)
+    rows = tr["prefill_tokens"] * m["k"] * layers
+    experts = tr["prefill_calls"] * m["n_experts"] * layers
     least = flops.least_time(flops.gmm_flops(m, rows),
                              flops.gmm_bytes(m, rows, experts), ctx["peak"])
     return _kernels.share(least, ctx["trace"].time_of(*_kernels.GMM,

@@ -10,9 +10,10 @@ def read(ctx):
     tr, m = ctx["run"].get("traced"), ctx["m"]
     if not tr:
         return None
+    layers = ctx["arch"].moe_layers(m)
     rows = (tr["tokens"] * m["k"] * (1.0 - tr["fraction_dropped"])
-            * m["n_layers"])
-    experts = tr["steps"] * m["n_experts"] * m["n_layers"]
+            * layers)
+    experts = tr["steps"] * m["n_experts"] * layers
     least = 3 * flops.least_time(flops.gmm_flops(m, rows),
                                  flops.gmm_bytes(m, rows, experts),
                                  ctx["peak"])

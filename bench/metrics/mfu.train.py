@@ -8,6 +8,7 @@ def read(ctx):
     tr = ctx["run"].get("traced")
     if not tr or tr["seconds"] <= 0:
         return None
-    fl = flops.train_flops(ctx["m"], ctx["run"]["seq_len"], tr["tokens"])
+    fl = flops.train_flops(ctx["arch"], ctx["m"], ctx["run"]["seq_len"],
+                           tr["tokens"])
     return 100.0 * fl / tr["seconds"] / (ctx["chips"]
                                         * ctx["peak"]["bf16_flops"])

@@ -8,6 +8,6 @@ def read(ctx):
     tr, m = ctx["run"].get("traced"), ctx["m"]
     if not tr or tr["decode_entries"] <= 0:
         return None
-    rows = (m["n_experts"] * reference.capacity(tr["n_slots"], m) * m["n_layers"]
-            * tr["decode_entries"])
+    rows = (m["n_experts"] * reference.capacity(tr["n_slots"], m)
+            * ctx["arch"].moe_layers(m) * tr["decode_entries"])
     return 100.0 * tr["decode_kept"] / rows

@@ -7,19 +7,21 @@ benchmark's own generators.  Every matrix product runs at
 ``precision="highest"``: a float32 product on a TPU otherwise rounds its
 inputs to bfloat16.
 
-One decoder layer, as the configuration files state it:
+The layers are the configuration's architecture's
+(``bench/archs/<arch>.py``): its module states each layer's mathematics,
+and may give layers of different kinds by index.  This module keeps what
+the architectures share and the loop around them:
 
-    h  = rmsnorm(x) ;  x = x + GQA-attention(h)           (RoPE, causal)
-    h  = rmsnorm(x) ;  x = x + MoE(h) [+ dense SwiGLU FFN(h)]
-    MoE: logits = h Wg ; top-k ; softmax over the k ; an expert takes the
-         first C assignments in batch order, the rest are dropped ;
-         y = sum_k w_k SwiGLU_k(h)
+    x = embed(tokens) ; x = layer_i(x) for each layer i ; rmsnorm
+    MoE (``moe``): logits = h Wg ; top-k ; softmax over the k ; an expert
+         takes the first C assignments in batch order, the rest are
+         dropped ; y = sum_k w_k SwiGLU_k(h)
     loss = mean token cross-entropy + sum over layers of
            w_imp CV^2(sum_t gates) + w_load CV^2(assignment counts)
 
-Memory: activations are recomputed per layer, attention runs a block of
-queries at a time and the experts one at a time, so a reference at the
-cells' sizes fits beside nothing else on one chip.
+Memory: activations are recomputed per layer, and the experts run one
+at a time (the architectures' attention a block of queries at a time),
+so a reference at the cells' sizes fits beside nothing else on one chip.
 
 ``control="fp8"`` rounds every weight matrix to float8 e4m3, with a
 scale per output channel, where it is used (the experts one at a time):
@@ -42,7 +44,6 @@ import numpy as np
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
-Q_BLOCK = 512
 LOSS_CHUNK = 1024
 W_IMPORTANCE = 0.1
 W_LOAD = 0.1
@@ -80,12 +81,12 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _use(w, name, control, axis=None):
+def _use(w, name, control, axis):
     """A weight as the forward pass reads it: as stored, or rounded to
     fp8 for the control (``axis``: the contracted axis)."""
     if not control:
         return w[name]
-    return fp8(w[name], _CONTRACT[name] if axis is None else axis)
+    return fp8(w[name], axis)
 
 
 def _act(x, control, axis=-1):
@@ -93,37 +94,6 @@ def _act(x, control, axis=-1):
     control, rounded to fp8 with a scale per row (``axis``: the
     contracted axes)."""
     return fp8(x, axis) if control == "fp8_train" else x
-
-
-def attention(w, h, m, control=None):
-    """Causal GQA over one sequence. h: [S, d] -> [S, d]."""
-    s = h.shape[0]
-    pos = jnp.arange(s)
-    h = _act(h, control)
-    q = rope(_mm("sd,dhk->shk", h, _use(w, "wq", control)), pos,
-             m["rope_theta"])
-    k = rope(_mm("sd,dhk->shk", h, _use(w, "wk", control)), pos,
-             m["rope_theta"])
-    v = _mm("sd,dhk->shk", h, _use(w, "wv", control))
-    kv, hd = k.shape[1], k.shape[2]
-    g = q.shape[1] // kv
-    qb = min(Q_BLOCK, s)
-    n = -(-s // qb)
-    q = jnp.pad(q, ((0, n * qb - s), (0, 0), (0, 0)))
-    qblocks = q.reshape(n, qb, kv, g, hd)
-
-    @jax.checkpoint
-    def block(args):
-        qi, i = args
-        sc = jnp.einsum("qkgh,skh->kgqs", qi, k, precision=HI) / math.sqrt(hd)
-        pq = i * qb + jnp.arange(qb)
-        sc = jnp.where(pos[None, :] <= pq[:, None], sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("kgqs,skh->qkgh", p, v, precision=HI)
-
-    o = jax.lax.map(block, (qblocks, jnp.arange(n)))
-    o = o.reshape(n * qb, kv * g, hd)[:s]
-    return _mm("shk,hkd->sd", _act(o, control, (1, 2)), _use(w, "wo", control))
 
 
 def cv_squared(x):
@@ -141,7 +111,7 @@ def moe(w, h, m, control=None):
     t = h.shape[0]
     e, k = m["n_experts"], m["k"]
     cap = capacity(t, m)
-    logits = _mm("td,de->te", _act(h, control), _use(w, "wg", control))
+    logits = _mm("td,de->te", _act(h, control), _use(w, "wg", control, 0))
     top, idx = jax.lax.top_k(logits, k)
     gate = jax.nn.softmax(top, axis=-1)                         # [T, k]
     gates = jnp.zeros((t, e), F32).at[jnp.arange(t)[:, None], idx].set(gate)
@@ -179,41 +149,21 @@ def moe(w, h, m, control=None):
 
 def mlp(w, h, control=None):
     h = _act(h, control)
-    a = _mm("td,df->tf", h, _use(w, "mlp_w1", control))
-    b = _mm("td,df->tf", h, _use(w, "mlp_w3", control))
+    a = _mm("td,df->tf", h, _use(w, "mlp_w1", control, 0))
+    b = _mm("td,df->tf", h, _use(w, "mlp_w3", control, 0))
     return _mm("tf,fd->td", _act(jax.nn.silu(a) * b, control),
-               _use(w, "mlp_w2", control))
+               _use(w, "mlp_w2", control, 0))
 
 
-LAYER_KEYS = ("ln1", "ln2", "wq", "wk", "wv", "wo", "wg", "w1", "w3", "w2",
-              "mlp_w1", "mlp_w3", "mlp_w2")
-# Contracted axis of each matrix (for the per-channel fp8 control).
-_CONTRACT = {"wq": 0, "wk": 0, "wv": 0, "wo": (0, 1), "wg": 0,
-             "mlp_w1": 0, "mlp_w3": 0, "mlp_w2": 0}
-
-
-def layer(params, i, x, m, control=None):
-    """Layer i. x: [B, S, d] -> (x, aux, kept).  The MoE routes all B*S
-    tokens of the call together, as one batch."""
-    w = {n: params[n][i] for n in LAYER_KEYS if n in params}
-    b, s, d = x.shape
-    h = rmsnorm(x, w["ln1"], m["eps"])
-    x = x + jax.lax.map(lambda hi: attention(w, hi, m, control), h)
-    h = rmsnorm(x, w["ln2"], m["eps"]).reshape(b * s, d)
-    y, aux, kept = moe(w, h, m, control)
-    if m["d_dense"]:
-        y = y + mlp(w, h, control)
-    return x + y.reshape(b, s, d), aux, kept
-
-
-def hidden(params, tokens, m, control=None):
-    """tokens [B, S] -> (final normed hidden [B, S, d] f32, aux, kept)."""
+def hidden(arch, params, tokens, m, control=None):
+    """tokens [B, S] -> (final normed hidden [B, S, d] f32, aux, kept),
+    through the layers of the architecture module ``arch``."""
     x = params["embed"][tokens].astype(F32)
     aux = jnp.zeros((), F32)
     kept = jnp.zeros((), jnp.int32)
     for i in range(m["n_layers"]):
         x, a, kp = jax.checkpoint(
-            lambda p, x_: layer(p, i, x_, m, control))(params, x)
+            lambda p, x_: arch.layer(p, i, x_, m, control))(params, x)
         aux, kept = aux + a, kept + kp
     return rmsnorm(x, params["ln_f"], m["eps"]), aux, kept
 
@@ -223,9 +173,9 @@ def _unembed(params, control):
     return fp8(w, 0) if control else w
 
 
-def loss_fn(params, batch, m, control=None):
+def loss_fn(arch, params, batch, m, control=None):
     """Mean token cross-entropy + balancing losses (the training loss)."""
-    x, aux, _ = hidden(params, batch["tokens"], m, control)
+    x, aux, _ = hidden(arch, params, batch["tokens"], m, control)
     b, s, d = x.shape
     un = _unembed(params, control)
     rows = x.reshape(b * s, d)
@@ -251,10 +201,10 @@ def _embed(params, tokens):
     return params["embed"][tokens].astype(F32)
 
 
-@functools.partial(jax.jit, static_argnames=("i", "mk", "control"),
-                   donate_argnums=(1,))
-def _layer_call(params, x, i, mk, control=None):
-    return layer(params, i, x, dict(mk), control)[0]
+@functools.partial(jax.jit, static_argnames=("arch", "i", "mk", "control"),
+                   donate_argnums=(2,))
+def _layer_call(arch, params, x, i, mk, control=None):
+    return arch.layer(params, i, x, dict(mk), control)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("mk", "control"))
@@ -263,16 +213,16 @@ def _head(params, x, mk, control=None):
     return _mm("bsd,dv->bsv", x, _unembed(params, control))[0]
 
 
-def _logits(params, tokens, mk, control=None):
+def _logits(arch, params, tokens, mk, control=None):
     """One sequence's logits, a layer per program so that one layer's
     float32 weights are live at a time."""
     x = _embed(params, tokens)
     for i in range(dict(mk)["n_layers"]):
-        x = _layer_call(params, x, i, mk, control)
+        x = _layer_call(arch, params, x, i, mk, control)
     return _head(params, x, mk, control)
 
 
-def served_logits(params, seqs, m, control=None, pad_to=None):
+def served_logits(arch, params, seqs, m, control=None, pad_to=None):
     """Logits at the positions that predicted each served token.
 
     ``seqs``: (prompt, served tokens) pairs.  A sequence is the prompt and
@@ -288,7 +238,7 @@ def served_logits(params, seqs, m, control=None, pad_to=None):
     for (prompt, served), n in zip(seqs, lens):
         row = np.zeros((1, width), np.int32)
         row[0, :n] = np.concatenate([prompt, served[:-1]])
-        lg = _logits(params, jnp.asarray(row), mk, control)
+        lg = _logits(arch, params, jnp.asarray(row), mk, control)
         out.append(np.asarray(lg[len(prompt) - 1:n]))
     return out
 
@@ -345,16 +295,16 @@ def _update(p, g, s, lr, scale, bias_correction):
     return (p.astype(F32) - lr * upd).astype(p.dtype), new_s
 
 
-@functools.partial(jax.jit, static_argnames=("mk", "control"))
-def _grads(params, batch, mk, control=None):
+@functools.partial(jax.jit, static_argnames=("arch", "mk", "control"))
+def _grads(arch, params, batch, mk, control=None):
     m = dict(mk)
     (loss, xent), g = jax.value_and_grad(
-        lambda p: loss_fn(p, batch, m, control), has_aux=True)(params)
+        lambda p: loss_fn(arch, p, batch, m, control), has_aux=True)(params)
     sq = {n: jnp.sum(jnp.square(v.astype(F32))) for n, v in g.items()}
     return loss, g, sq
 
 
-def train_steps(params, batches, m, opt, control=None):
+def train_steps(arch, params, batches, m, opt, control=None):
     """Follow the program's first steps.  ``opt`` holds lr and warmup.
 
     Returns losses per step, the first step's clipped gradient norm per
@@ -366,7 +316,7 @@ def train_steps(params, batches, m, opt, control=None):
     state = init_state(params)
     losses, first_norms = [], None
     for step, batch in enumerate(batches, start=1):
-        loss, g, sq = _grads(params, batch, mk, control)
+        loss, g, sq = _grads(arch, params, batch, mk, control)
         gnorm = math.sqrt(sum(float(v) for v in sq.values()))
         scale = min(1.0, CLIP / max(gnorm, 1e-9))
         if first_norms is None:

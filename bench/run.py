@@ -5,7 +5,8 @@
 
 Everything is found by name: the cell in ``BENCHMARK.json``, its
 settings in ``bench/workloads/<cell>.json``, its model configuration in
-``bench/configs/<config>.json``, and each per-layer metric's reader in
+``bench/configs/<config>.json``, the architecture that file names in
+``bench/archs/<arch>.py``, and each per-layer metric's reader in
 ``bench/metrics/<metric>.py``.  ``--trace 0`` reports the cell's
 end-to-end metrics, ``--trace 1`` its per-layer metrics from a profiler
 trace of the first seconds of the window.
@@ -52,8 +53,11 @@ def workload(name: str) -> dict:
         return json.load(f)
 
 
-def _applies(metric: dict, cell: str) -> bool:
-    return "workloads" not in metric or cell in metric["workloads"]
+def metrics_for(metrics: list[dict], cell: str) -> list[dict]:
+    """The entries of ``metrics`` that the cell reports: those without a
+    ``workloads`` list, and those whose list names it."""
+    return [m for m in metrics
+            if "workloads" not in m or cell in m["workloads"]]
 
 
 def reader(metric: str):
@@ -70,9 +74,7 @@ def reduce_layers(bench: dict, name: str, ctx: dict) -> dict:
     """Each per-layer metric of the cell from its reader; a reader that
     finds nothing to read returns None and the metric is left out."""
     out = {}
-    for metric in bench["per_layer"]:
-        if not _applies(metric, name):
-            continue
+    for metric in metrics_for(bench["per_layer"], name):
         value = reader(metric["name"]).read(ctx)
         if value is not None:
             out[metric["name"]] = {"value": float(value),
@@ -111,6 +113,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                              **cell["rehearsal"].get("check", {}))
     conf = model.load_config(entry["config"])
     m = model.dims(conf, rehearsal)
+    arch = model.arch(conf)
     devices = jax.devices()[: entry["chips"]]
     kind = devices[0].device_kind
     # a rehearsal's numbers only exercise the arithmetic: v5e peaks
@@ -137,14 +140,14 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
               "count": len(devices),
               "memory_peak_bytes": out["memory_peak_bytes"]}
     if trace:
-        ctx = {"cell": cell, "m": m, "peak": peak, "trace": tr,
-               "run": out, "chips": len(devices)}
+        ctx = {"cell": cell, "m": m, "arch": arch, "peak": peak,
+               "trace": tr, "run": out, "chips": len(devices)}
         metrics = reduce_layers(bench, name, ctx)
         device.update(busy_s=tr.busy_s(), window_s=tr.window_s)
     else:
         metrics = {mt["name"]: {"value": float(out["metrics"][mt["name"]]),
                                 "unit": mt["unit"]}
-                   for mt in bench["end_to_end"] if _applies(mt, name)}
+                   for mt in metrics_for(bench["end_to_end"], name)}
     result = {"correct": bool(correct), "attempted": int(out["attempted"]),
               "failed": int(out["failed"]), "metrics": metrics,
               "device": device}

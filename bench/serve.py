@@ -144,8 +144,9 @@ def run(cell: dict, conf: dict, m: dict, seed: int, seconds: float,
     if m["capacity_factor"] * m["k"] < m["n_experts"]:
         raise ValueError("serve cells route without drops: capacity_factor "
                          "must be at least n_experts / k")
-    cfg = model.program_config(conf, m, rehearsal=rehearsal)
-    params = weights.program_params(m, lm.lm_defs(cfg), seed)
+    arch = model.arch(conf)
+    cfg = arch.program_config(conf, m, rehearsal)
+    params = weights.program_params(arch, m, lm.lm_defs(cfg), seed)
     engine = ServeEngine(params, cfg, ServeConfig(
         max_len=eng["max_len"], n_slots=eng["n_slots"],
         prefill_chunk=eng["prefill_chunk"],
@@ -218,7 +219,7 @@ def run(cell: dict, conf: dict, m: dict, seed: int, seconds: float,
         if tracing and now >= t0 + trace_s:
             counters["end"] = snapshot(engine, now)
             counters["traced"] = traced_counts(
-                engine, flights, counters["start"], counters["end"], m)
+                engine, flights, counters["start"], counters["end"], m, arch)
             window.__exit__(None, None, None)
             jax.profiler.stop_trace()
             tracing = False
@@ -241,7 +242,7 @@ def run(cell: dict, conf: dict, m: dict, seed: int, seconds: float,
     if tracing:
         counters["end"] = snapshot(engine, clock())
         counters["traced"] = traced_counts(
-            engine, flights, counters["start"], counters["end"], m)
+            engine, flights, counters["start"], counters["end"], m, arch)
         window.__exit__(None, None, None)
         jax.profiler.stop_trace()
     # drain: every request due in the window gets its first token
@@ -278,7 +279,7 @@ def run(cell: dict, conf: dict, m: dict, seed: int, seconds: float,
     del engine, params, flights, live, due, done
     gc.collect()
     t_ref = clock()
-    nums = check.serve_numbers(seqs, m, seed, eng["max_len"], control)
+    nums = check.serve_numbers(arch, seqs, m, seed, eng["max_len"], control)
     out["reference_s"] = clock() - t_ref
     limits = cell["check"]["limits"]
     out["checks"] = check.verdict({k: nums[k] for k in limits}, limits)
@@ -292,12 +293,12 @@ def snapshot(engine, t: float) -> dict:
     return {"t": t, "step": engine.step_count, "stats": dict(engine.stats)}
 
 
-def traced_counts(engine, flights, a: dict, b: dict, m: dict) -> dict:
+def traced_counts(engine, flights, a: dict, b: dict, m: dict,
+                  arch) -> dict:
     """What the engine did between two snapshots: decode steps, decode
     rows kept and experts that got rows (from the per-step telemetry,
     summed over layers), prefill tokens and calls, and the model FLOPs of
     the tokens produced (decode) and the prompts completed (prefill)."""
-    from bench import flops
     entries = [e for e in engine.telemetry
                if a["step"] <= e["step"] < b["step"] and "expert_load" in e]
     kept = sum(float(np.sum(e["expert_load"] - e["overflow"]))
@@ -314,10 +315,10 @@ def traced_counts(engine, flights, a: dict, b: dict, m: dict) -> dict:
             if not a["t"] <= x <= b["t"]:
                 continue
             if j == 0:
-                f_pre += sum(flops.token_flops(m, p + 1)
+                f_pre += sum(arch.token_flops(m, p + 1)
                              for p in range(plen))
             else:
-                f_dec += flops.token_flops(m, plen + j)
+                f_dec += arch.token_flops(m, plen + j)
     return dict(d, seconds=b["t"] - a["t"], decode_kept=kept,
                 n_slots=engine.sc.n_slots,
                 decode_entries=len(entries), decode_touched=touched,

@@ -73,10 +73,11 @@ def run(cell: dict, conf: dict, m: dict, seed: int, seconds: float,
     data = dict(cell["data"])
     if rehearsal:
         data.update(cell["rehearsal"].get("data", {}))
-    cfg = model.program_config(conf, m, rehearsal=rehearsal)
+    arch = model.arch(conf)
+    cfg = arch.program_config(conf, m, rehearsal)
     defs = lm.lm_defs(cfg)
-    names = weights.leaf_names(defs)
-    params = weights.program_params(m, defs, seed)
+    names = weights.leaf_names(arch, m, defs)
+    params = weights.program_params(arch, m, defs, seed)
     opt = cell["optimizer"]
     workdir = tempfile.mkdtemp(prefix="bench-train-")
     trainer = Trainer(
@@ -120,7 +121,7 @@ def run(cell: dict, conf: dict, m: dict, seed: int, seconds: float,
         losses.append(float(met["loss"]))
         if i == 0:
             g1 = opt_grad_norms(trainer.state["opt"], names)
-    p0 = weights.program_params(m, defs, seed)
+    p0 = weights.program_params(arch, m, defs, seed)
     moved = change_norms(trainer.state["params"], p0, names)
     del p0
     fallbacks = sum(backend_lib.fallbacks().values())
@@ -182,14 +183,14 @@ def run(cell: dict, conf: dict, m: dict, seed: int, seconds: float,
     shutil.rmtree(workdir, ignore_errors=True)
     prog = {"losses": losses, "grad_norms": g1, "change_norms": moved}
     t_ref = clock()
-    ref = check.train_readings(m, seed, cell, data)
+    ref = check.train_readings(arch, m, seed, cell, data)
     out["reference_s"] = clock() - t_ref
     out["checks"] = check.verdict(check.train_numbers(prog, ref),
                                   cell["check"]["limits"])
     if control:
         out["control"] = {
             "fp8": check.train_numbers(check.train_readings(
-                m, seed, cell, data, control="fp8_train"), ref),
+                arch, m, seed, cell, data, control="fp8_train"), ref),
             "half_batch": check.train_numbers(check.train_readings(
-                m, seed, cell, data, half=True), ref)}
+                arch, m, seed, cell, data, half=True), ref)}
     return out

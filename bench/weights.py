@@ -1,11 +1,13 @@
 """Seeded weights, made on the device, in the benchmark's own naming.
 
-Every weight is one named leaf (``embed``, ``wq``, ``w1``, ...), drawn
-from a key folded from the run's seed and the leaf's name, so the same
-seed gives the same values wherever they are made.  Layer leaves carry a
-leading ``[layers]`` axis.  The program under test gets these values in
-its own parameter tree (``program_params``); the plain reference gets
-them under the names here (``make``).  Both draw them in one jitted call.
+Every weight is one named leaf (``embed``, ``w1``, ...), drawn from a
+key folded from the run's seed and the leaf's name, so the same seed
+gives the same values wherever they are made.  The configuration's
+architecture module (``bench/archs/<arch>.py``) names the leaves and
+their shapes (``shapes``) and where each sits in the program's tree
+(``PROGRAM_PATHS``).  The program under test gets these values in its
+own parameter tree (``program_params``); the plain reference gets them
+by name (``make``).  Both draw them in one jitted call.
 
 Scales keep every activation of the random-weight model O(1): unit
 normal embeddings, projections N(0, 1/fan_in), unit norm scales, and the
@@ -31,33 +33,6 @@ def base_key(seed: int) -> jax.Array:
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def shapes(m: dict) -> dict:
-    """name -> (shape, dtype, fan_in or None for ones/unit normal)."""
-    d, L, E, V = m["d_model"], m["n_layers"], m["n_experts"], m["vocab"]
-    H, KV, hd, f = m["n_heads"], m["n_kv_heads"], m["head_dim"], m["d_expert"]
-    out = {
-        "embed": ((V, d), BF16, None),
-        "unembed": ((d, V), BF16, d),
-        "ln_f": ((d,), F32, "ones"),
-        "ln1": ((L, d), F32, "ones"),
-        "ln2": ((L, d), F32, "ones"),
-        "wq": ((L, d, H, hd), BF16, d),
-        "wk": ((L, d, KV, hd), BF16, d),
-        "wv": ((L, d, KV, hd), BF16, d),
-        "wo": ((L, H, hd, d), BF16, H * hd),
-        "wg": ((L, d, E), F32, d),
-        "w1": ((L, E, d, f), BF16, d),
-        "w3": ((L, E, d, f), BF16, d),
-        "w2": ((L, E, f, d), BF16, f),
-    }
-    if m.get("d_dense"):
-        F = m["d_dense"]
-        out.update({"mlp_w1": ((L, d, F), BF16, d),
-                    "mlp_w3": ((L, d, F), BF16, d),
-                    "mlp_w2": ((L, F, d), BF16, F)})
-    return out
-
-
 def _leaf(key, name, shape, dtype, fan_in):
     if fan_in == "ones":
         return jnp.ones(shape, dtype)
@@ -68,44 +43,34 @@ def _leaf(key, name, shape, dtype, fan_in):
     return x.astype(dtype)
 
 
-def make(m: dict, seed: int) -> dict:
-    """All leaves by name, in one jitted call on the default device."""
-    spec = shapes(m)
+def make(arch, m: dict, seed: int) -> dict:
+    """All leaves of the architecture module ``arch`` by name, in one
+    jitted call on the default device."""
+    spec = arch.shapes(m)
 
     def build(key):
         return {n: _leaf(key, n, s, dt, fi) for n, (s, dt, fi) in spec.items()}
     return jax.jit(build)(base_key(seed))
 
 
-# The program's parameter paths (``repro.models.lm.lm_defs``) for each
-# leaf; layer leaves sit under the stacked scan group.
-_LAYER = "blocks/periods/pos0/"
-PROGRAM_PATHS = {
-    "embed/table": "embed", "unembed/w": "unembed", "ln_f/scale": "ln_f",
-    _LAYER + "ln1/scale": "ln1", _LAYER + "ln2/scale": "ln2",
-    _LAYER + "attn/wq": "wq", _LAYER + "attn/wk": "wk",
-    _LAYER + "attn/wv": "wv", _LAYER + "attn/wo": "wo",
-    _LAYER + "moe/gate/wg": "wg", _LAYER + "moe/w1": "w1",
-    _LAYER + "moe/w3": "w3", _LAYER + "moe/w2": "w2",
-    _LAYER + "mlp/w1": "mlp_w1", _LAYER + "mlp/w3": "mlp_w3",
-    _LAYER + "mlp/w2": "mlp_w2",
-}
-
-
 def _path(path) -> str:
     return "/".join(str(getattr(p, "key", p)) for p in path)
 
 
-def program_params(m: dict, defs, seed: int):
-    """The program's parameter tree (``defs`` from ``lm.lm_defs``) filled
-    with this module's leaves, made in one jitted call.  A program leaf
-    with no counterpart here, or of another shape or dtype, is an error."""
-    spec = shapes(m)
-    flat, tree = jax.tree_util.tree_flatten_with_path(
+def _flat(defs):
+    return jax.tree_util.tree_flatten_with_path(
         defs, is_leaf=lambda x: hasattr(x, "shape") and hasattr(x, "axes"))
+
+
+def leaf_names(arch, m: dict, defs) -> list[str]:
+    """Benchmark names of the program's leaves (``defs`` from
+    ``lm.lm_defs``), in the program's order.  A program leaf with no
+    counterpart in ``arch.shapes(m)``, or of another shape or dtype, is
+    an error."""
+    spec = arch.shapes(m)
     names = []
-    for path, d in flat:
-        name = PROGRAM_PATHS.get(_path(path))
+    for path, d in _flat(defs)[0]:
+        name = arch.PROGRAM_PATHS.get(_path(path))
         if name is None or name not in spec:
             raise KeyError(f"program parameter {_path(path)} has no "
                            "benchmark weight")
@@ -114,15 +79,17 @@ def program_params(m: dict, defs, seed: int):
             raise ValueError(f"{_path(path)}: program {d.shape} {d.dtype} "
                              f"vs benchmark {shape} {jnp.dtype(dtype)}")
         names.append(name)
+    return names
+
+
+def program_params(arch, m: dict, defs, seed: int):
+    """The program's parameter tree filled with the leaves of ``make``,
+    made in one jitted call."""
+    spec = arch.shapes(m)
+    names = leaf_names(arch, m, defs)
+    tree = _flat(defs)[1]
 
     def build(key):
         return jax.tree_util.tree_unflatten(
             tree, [_leaf(key, n, *spec[n]) for n in names])
     return jax.jit(build)(base_key(seed))
-
-
-def leaf_names(defs) -> list[str]:
-    """Benchmark names of the program's leaves, in the program's order."""
-    flat, _ = jax.tree_util.tree_flatten_with_path(
-        defs, is_leaf=lambda x: hasattr(x, "shape") and hasattr(x, "axes"))
-    return [PROGRAM_PATHS[_path(p)] for p, _ in flat]

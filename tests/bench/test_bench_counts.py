@@ -16,6 +16,8 @@ from bench import flops, model, serve, traffic  # noqa: E402
 
 KIMI = model.dims(model.load_config("kimi-k2-gqa-e16"))
 ARCTIC = model.dims(model.load_config("arctic-e8"))
+KIMI_ARCH = model.arch(model.load_config("kimi-k2-gqa-e16"))
+ARCTIC_ARCH = model.arch(model.load_config("arctic-e8"))
 
 
 def test_kimi_token_flops_hand_count():
@@ -26,7 +28,7 @@ def test_kimi_token_flops_hand_count():
              + 8 * 2 * 3 * d * 2048)            # 8 SwiGLU experts
     assert layer == 1_001_881_600
     total = 4 * layer + 2 * d * 20480           # 4 layers + unembedding
-    assert flops.token_flops(KIMI, 1000) == total == 4_301_127_680
+    assert KIMI_ARCH.token_flops(KIMI, 1000) == total == 4_301_127_680
 
 
 def test_arctic_train_flops_hand_count():
@@ -37,8 +39,8 @@ def test_arctic_train_flops_hand_count():
              + 2 * 2 * 3 * d * 4864             # top-2 experts
              + 2 * 3 * d * 7168)                # parallel dense FFN
     per_token = layer + 2 * d * 32000
-    assert flops.train_flops(ARCTIC, 4096, 8192) == pytest.approx(
-        3 * 8192 * per_token, rel=1e-12)
+    assert flops.train_flops(ARCTIC_ARCH, ARCTIC, 4096, 8192) == \
+        pytest.approx(3 * 8192 * per_token, rel=1e-12)
     assert 3 * per_token == pytest.approx(4.4374e9, rel=1e-4)
 
 
